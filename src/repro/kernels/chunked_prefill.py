@@ -15,6 +15,8 @@ to the block grid (padded K rows sit past ctx+Sc, so causality masks
 them; padded Q rows are zeroed by the epilogue and sliced off).
 
 Grid: (q_head, q_blocks, k_blocks); running-softmax scratch in VMEM.
+q/k/v are read through the lane-sliced ``(S, H*hd)`` view of
+``paged_attention.lane_view``: one ``(blk, hd)`` block per head.
 """
 from __future__ import annotations
 
@@ -24,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.paged_attention import lane_view
 
 NEG_INF = -1e30
 
@@ -59,13 +63,13 @@ def _kernel(ctx_ref,                                  # scalar prefetch
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v_ref[:, 0, :].astype(jnp.float32),
+            p, v_ref[...].astype(jnp.float32),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     def _scores():
-        q = q_ref[:, 0, :].astype(jnp.float32)        # (blk_q, hd)
-        k = k_ref[:, 0, :].astype(jnp.float32)        # (blk_k, hd)
+        q = q_ref[...].astype(jnp.float32)            # (blk_q, hd)
+        k = k_ref[...].astype(jnp.float32)            # (blk_k, hd)
         return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32) * scale
 
@@ -86,7 +90,7 @@ def _kernel(ctx_ref,                                  # scalar prefetch
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
         rows = iq * blk_q + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
         out = jnp.where(rows < sc_valid, out, 0.0)
-        out_ref[:, 0, :] = out.astype(out_ref.dtype)
+        out_ref[...] = out.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -122,11 +126,11 @@ def chunked_prefill_attention(q, k, v, ctx_len, *, blk_q: int = 128,
         num_scalar_prefetch=1,
         grid=(hq, sc_p // blk_q, t_p // blk_k),
         in_specs=[
-            pl.BlockSpec((blk_q, 1, hd), lambda h, iq, ik, c: (iq, h, 0)),
-            pl.BlockSpec((blk_k, 1, hd), lambda h, iq, ik, c: (ik, h // g, 0)),
-            pl.BlockSpec((blk_k, 1, hd), lambda h, iq, ik, c: (ik, h // g, 0)),
+            pl.BlockSpec((blk_q, hd), lambda h, iq, ik, c: (iq, h)),
+            pl.BlockSpec((blk_k, hd), lambda h, iq, ik, c: (ik, h // g)),
+            pl.BlockSpec((blk_k, hd), lambda h, iq, ik, c: (ik, h // g)),
         ],
-        out_specs=pl.BlockSpec((blk_q, 1, hd), lambda h, iq, ik, c: (iq, h, 0)),
+        out_specs=pl.BlockSpec((blk_q, hd), lambda h, iq, ik, c: (iq, h)),
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, 1), jnp.float32),
@@ -137,7 +141,9 @@ def chunked_prefill_attention(q, k, v, ctx_len, *, blk_q: int = 128,
         functools.partial(_kernel, blk_q=blk_q, blk_k=blk_k, scale=scale,
                           group=g, sc_valid=sc),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((sc_p, hq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((sc_p, hq * hd), q.dtype),
         interpret=interpret,
-    )(ctx, q, k, v)
+    )(ctx, lane_view(q, interpret), lane_view(k, interpret),
+      lane_view(v, interpret))
+    out = out.reshape(sc_p, hq, hd)
     return out[:sc] if sc_p != sc else out

@@ -20,6 +20,13 @@ Two schedules over the page dimension:
   cores/lanes for the long-context offline regime, and partitions whose
   pages lie entirely past ``ctx_len`` skip compute (ragged batches stop
   paying for the max context).
+
+Both read the pool through a lane-sliced view: the wrapper reshapes the
+``(P, bs, Hkv, hd)`` pool to ``(P, bs, Hkv*hd)`` (free for a contiguous
+array) and each grid step takes the ``(1, bs, hd)`` block at lane-block
+``h``. Mosaic tiles the last two block dims by (8, 128) unless they span
+the whole array dim, so a ``(.., 1, hd)`` head block is refused; the
+view only needs ``hd % 128 == 0`` (or a single KV head).
 """
 from __future__ import annotations
 
@@ -31,6 +38,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
+
+
+def lane_view(x, interpret: bool):
+    """(..., H, hd) -> (..., H*hd), the layout whose ``(.., hd)`` head
+    blocks the TPU compiler accepts. Raises on a compiled (non-interpret)
+    call whose head_dim the view cannot tile — never a silent fallback."""
+    *lead, h, hd = x.shape
+    if not interpret and h > 1 and hd % LANES:
+        raise ValueError(
+            f"TPU attention kernel cannot tile array of shape {x.shape}: "
+            f"a ({hd},)-lane head block of a {h * hd}-lane row needs "
+            f"head_dim % {LANES} == 0 (or one head)")
+    return x.reshape(*lead, h * hd)
 
 
 def _kernel(block_tables_ref, ctx_lens_ref,          # scalar prefetch (SMEM)
@@ -52,8 +73,8 @@ def _kernel(block_tables_ref, ctx_lens_ref,          # scalar prefetch (SMEM)
     @pl.when(i * page_size < ctx)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)             # (bs, hd)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         tok = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -91,10 +112,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         grid=(b, hkv, nblk),
         in_specs=[
             pl.BlockSpec((1, 1, g, hd), lambda bb, h, i, bt, cl: (bb, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda bb, h, i, bt, cl: (bt[bb, i], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda bb, h, i, bt, cl: (bt[bb, i], 0, h, 0)),
+            pl.BlockSpec((1, page_size, hd),
+                         lambda bb, h, i, bt, cl: (bt[bb, i], 0, h)),
+            pl.BlockSpec((1, page_size, hd),
+                         lambda bb, h, i, bt, cl: (bt[bb, i], 0, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd), lambda bb, h, i, bt, cl: (bb, h, 0, 0)),
         scratch_shapes=[
@@ -108,7 +129,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
         interpret=interpret,
-    )(block_tables, ctx_lens, qg, k_pages, v_pages)
+    )(block_tables, ctx_lens, qg, lane_view(k_pages, interpret),
+      lane_view(v_pages, interpret))
     return out.reshape(b, hq, hd)
 
 
@@ -135,8 +157,8 @@ def _splitk_kernel(block_tables_ref, ctx_lens_ref,    # scalar prefetch (SMEM)
     @pl.when(jnp.logical_and(i < nblk, i * page_size < ctx))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)           # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)              # (bs, hd)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         tok = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -194,12 +216,12 @@ def paged_attention_splitk(q, k_pages, v_pages, block_tables, ctx_lens,
         in_specs=[
             pl.BlockSpec((1, 1, g, hd),
                          lambda bb, h, s, j, bt, cl: (bb, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
+            pl.BlockSpec((1, page_size, hd),
                          lambda bb, h, s, j, bt, cl:
-                         (_page(bb, h, s, j, bt, cl), 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
+                         (_page(bb, h, s, j, bt, cl), 0, h)),
+            pl.BlockSpec((1, page_size, hd),
                          lambda bb, h, s, j, bt, cl:
-                         (_page(bb, h, s, j, bt, cl), 0, h, 0)),
+                         (_page(bb, h, s, j, bt, cl), 0, h)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, g, hd),
@@ -225,7 +247,8 @@ def paged_attention_splitk(q, k_pages, v_pages, block_tables, ctx_lens,
             jax.ShapeDtypeStruct((b, hkv, nsplit, g, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(block_tables, ctx_lens, qg, k_pages, v_pages)
+    )(block_tables, ctx_lens, qg, lane_view(k_pages, interpret),
+      lane_view(v_pages, interpret))
 
     # cross-partition combine: one exp re-base per partition, one divide
     # total. Empty partitions (m=-inf, l=0, acc=0) drop out of both sums.

@@ -71,15 +71,6 @@ def collective_bytes(hlo_text: str) -> dict:
     return out
 
 
-def _cost_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a dict on new jax and a
-    one-element list of dicts on older releases (e.g. 0.4.x) — normalize."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def _cache_len(cfg, shape) -> int:
     return Model(cfg).attn_cache_len(shape.seq_len)
 
@@ -128,7 +119,7 @@ def _measure(cfg, shape, mesh) -> dict:
     fn, lower_args = build_step(cfg, shape, mesh)
     lowered = fn.lower(*lower_args)
     compiled = lowered.compile()
-    ca = _cost_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -189,7 +180,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             lowered = fn.lower(*lower_args)
             compiled = lowered.compile()
         rec["compile_s"] = round(time.time() - t0, 1)
-        ca = _cost_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         rec["flops"] = float(ca.get("flops", 0.0))
         rec["bytes_accessed"] = float(ca.get("bytes accessed", 0.0))
         try:

@@ -1,8 +1,10 @@
-"""Co-serving driver: run the Echo engine on a reduced-family model.
+"""Co-serving entry point: run the Echo engine on a real model.
 
-The full assigned configs are exercised by the dry-run (``dryrun.py``);
-this driver serves a runnable-on-CPU reduced variant with a real bursty
-online trace + offline batch corpus, and prints the paper's metrics.
+On an accelerator it serves the model at its published widths
+(seeded random weights); on the CPU it serves the ``.reduced()`` variant
+(2 layers, float32). It replays a bursty online trace + offline batch
+corpus and prints the paper's metrics. ``build_engine`` is the one
+constructor of a single serving engine (``chip_smoke.py`` uses it too).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
@@ -54,6 +56,7 @@ import dataclasses
 import jax
 
 from repro.configs import ARCH_IDS, get_config
+from repro.configs.base import ModelConfig
 from repro.core import ALL_POLICIES, SLO, EchoEngine, TimeModel
 from repro.core.block_io import BlockIOSpec, io_spec_for_model, paged_spec
 from repro.data import BurstyTrace, make_offline_corpus, make_online_requests
@@ -65,17 +68,66 @@ POLICY_BY_NAME = {p.name: p for p in ALL_POLICIES}
 DEFAULT_ARCH = "qwen3-4b"
 
 
-def host_kv_blocks(args, io: BlockIOSpec = None,
+def host_kv_blocks(host_kv_gb: float, io: BlockIOSpec = None,
                    block_size: int = 16) -> int:
-    """--host-kv-gb translated to host-tier slots through the served
-    family's block I/O spec (0 with --no-swap): one slot parks one block's
-    payload — a page of KV for attention models, one fixed-size state
-    snapshot for SSM/hybrid ones — so the same GB budget buys far more
-    slots on a state-family model."""
-    if args.no_swap or args.host_kv_gb <= 0:
+    """A host-tier budget in GB translated to slots through the served
+    family's block I/O spec: one slot parks one block's payload — a page
+    of KV for attention models, one fixed-size state snapshot for
+    SSM/hybrid ones — so the same GB budget buys far more slots on a
+    state-family model."""
+    if host_kv_gb <= 0:
         return 0
     per_block = max((io or paged_spec()).block_bytes(block_size), 1)
-    return max(int(args.host_kv_gb * 1e9 / per_block), 1)
+    return max(int(host_kv_gb * 1e9 / per_block), 1)
+
+
+def host_kv_gb(args) -> float:
+    """--host-kv-gb, or 0 with --no-swap."""
+    return 0.0 if args.no_swap else args.host_kv_gb
+
+
+def serving_config(arch: str = None) -> ModelConfig:
+    """The config ``serve`` runs: the published widths on an accelerator,
+    the ``.reduced()`` cut on the CPU."""
+    cfg = get_config(arch or DEFAULT_ARCH)
+    return cfg.reduced() if jax.default_backend() == "cpu" else cfg
+
+
+def build_engine(cfg: ModelConfig, policy, *, num_blocks: int,
+                 seed: int = 0, host_kv_gb: float = 0.0,
+                 pcie_gbps: float = 25.0, swap_overlap: bool = True,
+                 block_size: int = 16, **engine_kw) -> EchoEngine:
+    """The one constructor of a single serving engine. With ``cfg`` it
+    serves that model with random weights drawn from ``seed``;
+    ``cfg=None`` builds the model-free virtual-clock engine. The
+    scheduler's estimate is the ``TimeModel.a100`` preset: no v5e preset
+    has been fitted. ``engine_kw`` goes to ``EchoEngine``."""
+    model = params = io = None
+    quad = True
+    if cfg is not None:
+        model = Model(cfg)
+        # jitted init: the float32 draws are never all live beside the
+        # (bf16) params, as they are in an eager init
+        params = jax.jit(model.init)(jax.random.PRNGKey(seed))
+        quad = cfg.family not in ("ssm", "hybrid")
+        io = io_spec_for_model(model)
+    tm = TimeModel.a100(quadratic_prefill=quad,
+                        swap_byte=TimeModel.pcie_swap_byte(pcie_gbps),
+                        swap_overlap=swap_overlap)
+    return EchoEngine(model, params, policy, num_blocks=num_blocks,
+                      block_size=block_size, time_model=tm,
+                      host_kv_blocks=host_kv_blocks(host_kv_gb, io,
+                                                    block_size),
+                      **engine_kw)
+
+
+def engine_flags(args) -> dict:
+    """``build_engine`` keywords set by the CLI flags (replay and --serve
+    share them)."""
+    return dict(num_blocks=args.num_blocks, seed=args.seed,
+                host_kv_gb=host_kv_gb(args), pcie_gbps=args.pcie_gbps,
+                swap_overlap=not args.no_swap_overlap,
+                attn_impl=args.attn_impl, kernel_profile=args.kernel_profile)
 
 
 def admission_config(args):
@@ -229,42 +281,6 @@ def clock_models(args, *, quadratic_prefill: bool = True,
     return out
 
 
-def calibrate(model: Model, params, *, chunk_size=64, num_blocks=192,
-              block_size=16) -> TimeModel:
-    """Fit the Eq.6-8 coefficients by micro-benchmarking the runner (§6)."""
-    import time as _t
-
-    from repro.models.paged import PagedRunner
-    runner = PagedRunner(model, params, num_blocks, block_size,
-                         max_pages_per_seq=num_blocks // 2, chunk_size=chunk_size)
-    tm = TimeModel(quadratic_prefill=model.cfg.family not in ("ssm", "hybrid"))
-    # prefill samples
-    samples = []
-    for l in (16, 32, 48, 64):
-        toks = list(range(l))
-        bt = list(range((l + block_size - 1) // block_size + 1))
-        runner.prefill_chunk(toks, 0, bt)                  # warm
-        t0 = _t.perf_counter()
-        for _ in range(3):
-            runner.prefill_chunk(toks, 0, bt)
-        samples.append((l, (_t.perf_counter() - t0) / 3))
-    tm.fit_prefill(samples)
-    # decode samples
-    dsamples = []
-    for b in (1, 4, 8):
-        toks = [1] * b
-        bts = [[i] for i in range(b)]
-        pos = [0] * b
-        runner.decode(toks, bts, pos)
-        t0 = _t.perf_counter()
-        for _ in range(3):
-            runner.decode(toks, bts, pos)
-        t = (_t.perf_counter() - t0) / 3
-        dsamples.append((1, 1.0, t))
-    tm.fit_decode(dsamples)
-    return tm
-
-
 def chaos_config(args):
     """ChaosConfig from --kill-at/--degrade-at specs; None when unused."""
     kills, degrades = [], []
@@ -320,7 +336,7 @@ def serve_cluster(args) -> None:
                            time_model=tm,
                            clock_models=clock_models(args,
                                                      swap_byte=swap_byte),
-                           host_kv_blocks=host_kv_blocks(args),
+                           host_kv_blocks=host_kv_blocks(host_kv_gb(args)),
                            seed=args.seed, chaos=chaos_config(args),
                            autoscaler=autoscaler_for(args))
     service = EchoService(sim, admission=admission_config(args))
@@ -344,35 +360,33 @@ def serve_realtime(args) -> None:
     from repro.rt.calibrate import calibrate_link
 
     policy = resolve_policy(args)
-    swap_byte = TimeModel.pcie_swap_byte(args.pcie_gbps)
-    quad, io, model, params = True, None, None, None
-    if args.replicas == 1 and not args.virtual:
-        cfg = get_config(args.arch or DEFAULT_ARCH).reduced()
-        model = Model(cfg)
-        params = model.init(jax.random.PRNGKey(args.seed))
-        quad = cfg.family not in ("ssm", "hybrid")
-        io = io_spec_for_model(model)
-    tm = TimeModel.a100(quadratic_prefill=quad, swap_byte=swap_byte,
-                        swap_overlap=not args.no_swap_overlap)
-    # cold-start link calibration: measure the real host<->device path and
-    # refit the swap terms BEFORE the first request is priced against them
-    if not args.no_link_calibration:
-        print(calibrate_link(tm).summary())
+
+    def calibrated(tm: TimeModel) -> TimeModel:
+        # cold-start link calibration: measure the real host<->device path
+        # and refit the swap terms (in place) BEFORE the first request is
+        # priced against them
+        if not args.no_link_calibration:
+            print(calibrate_link(tm).summary())
+        return tm
+
+    cfg = None
     if args.replicas > 1:
         from repro.cluster import ClusterSimulator
+        tm = TimeModel.a100(swap_byte=TimeModel.pcie_swap_byte(args.pcie_gbps),
+                            swap_overlap=not args.no_swap_overlap)
+        # every replica gets a copy of the calibrated template
         target = ClusterSimulator(args.replicas, policy,
                                   router_policy=args.router,
-                                  num_blocks=args.num_blocks, time_model=tm,
-                                  host_kv_blocks=host_kv_blocks(args),
+                                  num_blocks=args.num_blocks,
+                                  time_model=calibrated(tm),
+                                  host_kv_blocks=host_kv_blocks(
+                                      host_kv_gb(args)),
                                   seed=args.seed)
     else:
-        target = EchoEngine(model, params, policy,
-                            num_blocks=args.num_blocks, block_size=16,
-                            chunk_size=64, max_pages_per_seq=32,
-                            time_model=tm,
-                            host_kv_blocks=host_kv_blocks(args, io),
-                            attn_impl=args.attn_impl,
-                            kernel_profile=args.kernel_profile)
+        if not args.virtual:
+            cfg = serving_config(args.arch)
+        target = build_engine(cfg, policy, **engine_flags(args))
+        calibrated(target.tm)      # the scheduler holds this very model
     rt = AsyncEchoEngine(target, admission=admission_config(args))
     tracer, registry = None, None
     if args.trace_out or args.metrics_out:
@@ -392,8 +406,7 @@ def serve_realtime(args) -> None:
         srv = await EchoServer(rt, host=args.host, port=args.port).start()
         host, port = srv.address
         mode = (f"{args.replicas} virtual replicas" if args.replicas > 1
-                else ("virtual engine" if model is None
-                      else f"{(args.arch or DEFAULT_ARCH)} (reduced)"))
+                else ("virtual engine" if cfg is None else cfg.name))
         print(f"listening on {host}:{port} — {mode}, policy={policy.name}; "
               "newline-delimited JSON, Ctrl-C to drain")
         if args.serve_duration > 0:
@@ -467,9 +480,9 @@ def main() -> None:
                          "auto = jnp oracle on CPU / split-K Pallas on "
                          "accelerators (see repro.kernels.ops)")
     ap.add_argument("--kernel-profile", default=None,
-                    choices=["a100", "h100", "cpu"],
+                    choices=["v5e", "cpu"],
                     help="kernel block-size tuning table (default: resolve "
-                         "from the jax backend)")
+                         "from the attached device's kind)")
     ap.add_argument("--hw-drift", type=float, default=1.0,
                     help="scale the ground-truth clock by this factor "
                          "(2.0 = hardware runs 2x slower than the estimate)")
@@ -547,17 +560,11 @@ def main() -> None:
         serve_cluster(args)
         return
 
-    cfg = get_config(args.arch or DEFAULT_ARCH).reduced()
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    cfg = serving_config(args.arch)
     policy = resolve_policy(args)
-
     quad = cfg.family not in ("ssm", "hybrid")
-    io = io_spec_for_model(model)
-    swap_byte = TimeModel.pcie_swap_byte(args.pcie_gbps)
-    tm = TimeModel.a100(quadratic_prefill=quad, swap_byte=swap_byte,
-                        swap_overlap=not args.no_swap_overlap)
-    clocks = clock_models(args, quadratic_prefill=quad, swap_byte=swap_byte)
+    clocks = clock_models(args, quadratic_prefill=quad,
+                          swap_byte=TimeModel.pcie_swap_byte(args.pcie_gbps))
     if clocks and len(clocks) > 1:
         print(f"warning: --replicas 1 uses only the first --hw-profile "
               f"({args.hw_profile.split(',')[0].strip()}); extra profiles "
@@ -572,13 +579,9 @@ def main() -> None:
                                   question_len=24, max_new=8,
                                   vocab=cfg.vocab_size, seed=args.seed + 1)
 
-    eng = EchoEngine(model, params, policy, num_blocks=args.num_blocks,
-                     block_size=16, chunk_size=64,
-                     max_pages_per_seq=32, time_model=tm,
-                     clock_model=clocks[0] if clocks else None,
-                     host_kv_blocks=host_kv_blocks(args, io),
-                     attn_impl=args.attn_impl,
-                     kernel_profile=args.kernel_profile)
+    eng = build_engine(cfg, policy,
+                       clock_model=clocks[0] if clocks else None,
+                       **engine_flags(args))
     service = EchoService(eng, admission=admission_config(args))
     tracer, registry = setup_obs(args, service)
     stats = service.drive(online + offline, max_iters=100_000,

@@ -115,7 +115,7 @@ class PagedRunner:
         # attention kernel dispatch: "auto" runs the jnp oracles on CPU and
         # the split-K Pallas path on accelerators; "ref"/"pallas"/"splitk"
         # force one. kernel_profile picks the block-size tuning table
-        # (None resolves by backend — see repro.kernels.ops).
+        # (None resolves by device kind — see repro.kernels.ops).
         self.attn_impl = attn_impl
         self.kernel_profile = kernel_profile
         self.tuning = kops.kernel_tuning(kernel_profile)

@@ -11,16 +11,23 @@ transfer with a jitted matmul to recover the async-copy launch overhead
 via ``TimeModel.fit_swap_overlap`` — all before the first request is
 admitted.
 
-Everything degrades gracefully: no jax, a CPU-only platform where
-"device" transfers are memcpys, or a degenerate fit (zero byte rate)
-leaves the preset terms untouched and reports why.
+A degenerate fit (zero byte rate) on the CPU backend, where "device"
+transfers are memcpys that alias host memory, leaves the preset terms
+untouched and reports why. Anywhere else a failed measurement or a
+degenerate fit raises: a server does not start on a link it could not
+measure.
 """
 from __future__ import annotations
 
 import logging
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 logger = logging.getLogger(__name__)
 
@@ -60,24 +67,11 @@ class LinkCalibration:
                 f"({len(self.samples)} transfer samples)")
 
 
-def _import_jax():
-    try:
-        import jax
-        import jax.numpy as jnp
-        return jax, jnp
-    except Exception:                  # ImportError or broken install
-        return None, None
-
-
 def measure_link(sizes=DEFAULT_SIZES,
-                 repeats: int = 3) -> Optional[List[Tuple[int, float]]]:
+                 repeats: int = 3) -> List[Tuple[int, float]]:
     """Time real host->device and device->host transfers. Returns
     ``(n_bytes, seconds)`` samples (both directions pooled — the fit
-    recovers one effective link rate), or None without jax."""
-    jax, _ = _import_jax()
-    if jax is None:
-        return None
-    import numpy as np
+    recovers one effective link rate)."""
     samples: List[Tuple[int, float]] = []
     for n in sizes:
         buf = np.zeros(n, dtype=np.uint8)
@@ -99,11 +93,6 @@ def measure_overlap(tm, sizes=DEFAULT_SIZES, repeats: int = 2,
     """Overlap a ``device_put`` (issued from a helper thread) with a jitted
     matmul and time the pair — ``(compute_s, n_bytes, total_s)`` samples
     for ``fit_swap_overlap``'s max-plus-launch residual."""
-    jax, jnp = _import_jax()
-    if jax is None:
-        return []
-    import numpy as np
-    from concurrent.futures import ThreadPoolExecutor
     x = jnp.ones((matmul_dim, matmul_dim), jnp.float32)
     step = jax.jit(lambda a: a @ a)
     jax.block_until_ready(step(x))                 # compile
@@ -129,45 +118,34 @@ def calibrate_link(tm, *, sizes=DEFAULT_SIZES, repeats: int = 3,
                    overlap: bool = True) -> LinkCalibration:
     """Measure the real link and refit ``tm``'s swap terms in place.
 
-    On any failure — jax missing, too few samples, or a degenerate fit
-    (non-positive byte rate, as on backends where device transfers are
-    aliasing memcpys) — the model's preset terms are restored untouched
-    and the returned record says why."""
+    On the CPU backend a degenerate fit (non-positive byte rate: device
+    buffers alias host memory) restores the model's preset terms and the
+    returned record says why. On an accelerator it raises, as does any
+    failed measurement."""
     snapshot = (tm.swap_byte, tm.swap_floor, tm.swap_launch)
-
-    def _skip(reason: str, backend: str = "unavailable") -> LinkCalibration:
+    backend = jax.default_backend()
+    samples = measure_link(sizes, repeats)
+    tm.fit_swap(samples)
+    # a fitted rate implying > ~1 PB/s is float noise from size-blind
+    # timings: nothing real was measured
+    if tm.swap_byte < 1e-15:
         tm.swap_byte, tm.swap_floor, tm.swap_launch = snapshot
+        reason = "degenerate fit: measured byte rate ~ 0"
+        if backend != "cpu":
+            raise RuntimeError(f"link calibration on {backend}: {reason}")
         return LinkCalibration(applied=False, backend=backend,
                                swap_byte=tm.swap_byte,
                                swap_floor=tm.swap_floor,
                                swap_launch=tm.swap_launch, error=reason)
-
-    jax, _ = _import_jax()
-    if jax is None:
-        return _skip("jax not importable")
-    try:
-        backend = jax.default_backend()
-        samples = measure_link(sizes, repeats) or []
-        if len(samples) < 2:
-            return _skip("too few transfer samples", backend)
-        tm.fit_swap(samples)
-        # a fitted rate implying > ~1 PB/s is float noise from size-blind
-        # timings (device buffer aliases host memory): nothing real was
-        # measured, keep the nominal link pricing
-        if tm.swap_byte < 1e-15:
-            return _skip("degenerate fit: measured byte rate ~ 0", backend)
-        overlap_samples: List[Tuple[float, int, float]] = []
-        if overlap:
-            overlap_samples = measure_overlap(tm, sizes)
-            tm.fit_swap_overlap(overlap_samples)
-        cal = LinkCalibration(applied=True, backend=backend,
-                              swap_byte=tm.swap_byte,
-                              swap_floor=tm.swap_floor,
-                              swap_launch=tm.swap_launch,
-                              samples=samples,
-                              overlap_samples=overlap_samples)
-        logger.info("%s", cal.summary())
-        return cal
-    except Exception as exc:           # never let calibration kill startup
-        logger.warning("link calibration failed", exc_info=True)
-        return _skip(f"{type(exc).__name__}: {exc}")
+    overlap_samples: List[Tuple[float, int, float]] = []
+    if overlap:
+        overlap_samples = measure_overlap(tm, sizes)
+        tm.fit_swap_overlap(overlap_samples)
+    cal = LinkCalibration(applied=True, backend=backend,
+                          swap_byte=tm.swap_byte,
+                          swap_floor=tm.swap_floor,
+                          swap_launch=tm.swap_launch,
+                          samples=samples,
+                          overlap_samples=overlap_samples)
+    logger.info("%s", cal.summary())
+    return cal

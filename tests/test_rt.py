@@ -400,17 +400,6 @@ def test_fit_swap_recovers_synthetic_link():
     assert tm.swap_floor == pytest.approx(floor, rel=1e-6)
 
 
-def test_calibrate_link_without_jax_keeps_presets(monkeypatch):
-    tm = TimeModel.a100()
-    before = (tm.swap_byte, tm.swap_floor, tm.swap_launch)
-    monkeypatch.setattr(calibrate_mod, "_import_jax",
-                        lambda: (None, None))
-    cal = calibrate_link(tm)
-    assert not cal.applied
-    assert cal.error == "jax not importable"
-    assert (tm.swap_byte, tm.swap_floor, tm.swap_launch) == before
-
-
 def test_calibrate_link_degenerate_fit_restores_presets(monkeypatch):
     tm = TimeModel.a100()
     before = (tm.swap_byte, tm.swap_floor, tm.swap_launch)
@@ -423,9 +412,32 @@ def test_calibrate_link_degenerate_fit_restores_presets(monkeypatch):
     assert (tm.swap_byte, tm.swap_floor, tm.swap_launch) == before
 
 
+def test_calibrate_link_degenerate_fit_raises_off_cpu(monkeypatch):
+    """Only the CPU backend, whose device buffers alias host memory, may
+    keep the presets; an accelerator link that measures nothing is an
+    error, not a silent skip."""
+    tm = TimeModel.a100()
+    before = (tm.swap_byte, tm.swap_floor, tm.swap_launch)
+    monkeypatch.setattr(calibrate_mod, "measure_link",
+                        lambda sizes, repeats: [(1 << 18, 1e-4),
+                                                (1 << 22, 1e-4)])
+    monkeypatch.setattr(calibrate_mod.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="degenerate"):
+        calibrate_link(tm, overlap=False)
+    assert (tm.swap_byte, tm.swap_floor, tm.swap_launch) == before
+
+
+def test_calibrate_link_measurement_error_propagates(monkeypatch):
+    def broken(sizes, repeats):
+        raise OSError("transfer failed")
+    monkeypatch.setattr(calibrate_mod, "measure_link", broken)
+    with pytest.raises(OSError, match="transfer failed"):
+        calibrate_link(TimeModel.a100())
+
+
 def test_calibrate_link_real_backend_smoke():
-    """With jax present the calibration must either apply a positive byte
-    rate or explain why it kept the presets — and never raise."""
+    """On the CPU backend the calibration must either apply a positive
+    byte rate or explain why it kept the presets — and never raise."""
     tm = TimeModel.a100()
     cal = calibrate_link(tm, sizes=(1 << 16, 1 << 18), repeats=1)
     if cal.applied:
