@@ -9,6 +9,7 @@ import numpy as np
 
 from benchmarks.scenario import build_engine
 from repro.core import ALL_POLICIES, BS, ECHO
+from repro.core.engine import EngineListener
 from repro.core.estimator import RatePredictor
 from repro.data import BurstyTrace
 
@@ -100,10 +101,24 @@ def fig9_hit_rate():
     return rows
 
 
+class _UsageRecorder(EngineListener):
+    """The block pool's occupancy breakdown after every step."""
+
+    def __init__(self, bm):
+        self.bm = bm
+        self.usages = []
+
+    def on_iteration(self, rec, detail):
+        self.usages.append(self.bm.usage_breakdown())
+
+
 def fig10_memory():
     """Memory occupancy breakdown (paper Fig. 10)."""
-    eng, stats, wall, _ = _run(ECHO)
-    usages = [r.usage for r in stats.iterations]
+    eng, _, _, p = build_engine(ECHO, seed=0)
+    rec = _UsageRecorder(eng.bm)
+    eng.listeners.append(rec)
+    eng.run(max_iters=200_000, until_time=p["duration"])
+    usages = rec.usages
     keys = ("running_online", "running_offline", "free_online",
             "free_offline", "unused")
     total = eng.bm.num_blocks

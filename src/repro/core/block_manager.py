@@ -40,6 +40,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.block_io import BlockIOSpec, paged_spec
 from repro.core.request import Request, TaskType
+from repro.obs.spans import spanned
 
 ONLINE_PREEMPTED_PRIORITY = 1e9
 ONLINE_FINISHED_PRIORITY = 0.5
@@ -709,6 +710,7 @@ class BlockManager:
                 prev = chain_hash(prev, tuple(tokens[bi * bs: (bi + 1) * bs]))
         return prev
 
+    @spanned("echo.kv.commit")
     def commit(self, req: Request, tokens: Sequence[int], now: float) -> None:
         """Register hashes for req's now-full computed blocks (content known)."""
         bs = self.block_size

@@ -37,6 +37,7 @@ from repro.core.request import Request, RequestState
 from repro.core.scheduler import Scheduler
 from repro.models.model import Model
 from repro.models.paged import PagedRunner
+from repro.obs.spans import span, step_span
 
 MAX_STALLS = 3      # consecutive no-progress iterations before giving up
 
@@ -51,7 +52,6 @@ class IterationRecord:
     iter_time: float
     offline_tokens: int
     online_tokens: int
-    usage: Dict[str, int] = field(default_factory=dict)
     hit_rate: float = 0.0
     threshold_blocks: int = 0
     swap_in_tokens: int = 0        # tokens restored from the host tier
@@ -65,19 +65,60 @@ class IterationRecord:
 
 
 @dataclass
+class StepTimes:
+    """Wall seconds of one engine step by phase, on ``time.perf_counter``.
+
+    Each phase is timed at the boundary of the ``echo.*`` profiler span of
+    the same name (``repro.obs.spans``), summed over the step; the phases
+    do not overlap, so they sum to at most ``t_end - t_start``.
+    ``observe`` (the ``on_iteration`` listeners) runs after ``t_end`` and
+    is filled in once they return."""
+    t_start: float = 0.0        # step start
+    t_exec: float = 0.0         # start of the runner window
+    t_end: float = 0.0          # end of the step's work
+    schedule: float = 0.0       # echo.sched: scheduler.schedule
+    swap: float = 0.0           # echo.swap: launching KV staging
+    prep: float = 0.0           # echo.runner.prep: inputs to the device
+    launch: float = 0.0         # echo.runner.launch: the jitted call
+    wait: float = 0.0           # echo.runner.wait: blocked on the device
+    fetch: float = 0.0          # echo.runner.fetch: logits to the host
+    argmax: float = 0.0         # echo.argmax: greedy sampling on the host
+    commit: float = 0.0         # echo.kv.commit: BlockManager.commit
+    emit: float = 0.0           # echo.emit: tokens, finishes, listeners
+    threshold: float = 0.0      # echo.kv.threshold: §5.3 threshold
+    observe: float = 0.0        # echo.observe: on_iteration listeners
+    n_launches: int = 0         # runner programs dispatched
+    n_syncs: int = 0            # blocking device-to-host fetches
+
+    @property
+    def wall(self) -> float:
+        return self.t_end - self.t_start
+
+    @property
+    def host(self) -> float:
+        """Step wall time not spent blocked on the device."""
+        return self.wall - self.wait
+
+
+@dataclass
 class IterationDetail:
     """What the observability layer needs beyond ``IterationRecord``: the
-    plan's shape and the estimate it was scored with. Built only when a
-    listener overrides ``on_iteration`` — the plain serving path never
-    pays for it."""
+    plan's shape, the estimate it was scored with and the step's wall times
+    by phase. Built only when a listener overrides ``on_iteration`` — the
+    plain serving path never pays for it."""
     t_start: float
     t_end: float
-    schedule_wall: float           # wall seconds spent in scheduler.schedule
     compute_time: float            # the clock's compute leg (no transfers)
     predicted_time: float          # scheduler estimate of the iteration
     admitted: List[Request]        # newly admitted to the running batch
     prefill_spans: List[Tuple[Request, int, int]]   # (req, start, end)
     decodes: List[Request]
+    times: StepTimes = field(default_factory=StepTimes)
+
+    @property
+    def schedule_wall(self) -> float:
+        """Wall seconds spent in ``scheduler.schedule``."""
+        return self.times.schedule
 
 
 class EngineListener:
@@ -392,6 +433,7 @@ class EchoEngine:
         # concurrent driver must fail loudly instead of corrupting the
         # scheduler/KV state mid-iteration
         self._step_lock = threading.Lock()
+        self._n_steps = 0
 
     # ------------------------------------------------------------- intake
     def submit(self, req: Request) -> None:
@@ -454,10 +496,9 @@ class EchoEngine:
         out[rng.integers(0, 128)] = 1.0
         return out
 
-    def _emit(self, req: Request, logits: np.ndarray) -> None:
+    def _emit(self, req: Request, tok: int) -> None:
         if req.state == RequestState.ABORTED:
             return          # aborted from a listener callback this iteration
-        tok = int(np.argmax(logits))
         req.record_token(tok, self.now)
         for l in self.listeners:
             l.on_token(req, tok, self.now)
@@ -522,7 +563,7 @@ class EchoEngine:
         n += sum(1 for r in self.scheduler.running if not r.is_online)
         return n
 
-    def _execute_swaps(self) -> Tuple[int, int, int]:
+    def _execute_swaps(self, times: StepTimes) -> Tuple[int, int, int]:
         """Dispatch the block staging of this iteration's swap decisions.
 
         With the async stager (wall path, overlap on) this only *launches*
@@ -537,28 +578,33 @@ class EchoEngine:
         from the plan, but the link-clocked byte weights come from the
         journal, where "in_lazy" restores correctly weigh zero."""
         events = self.bm.drain_swap_events()
-        out_tokens = sum(hb.n_tokens for kind, _, hb in events
-                         if kind == "out")
-        out_bytes = sum(hb.n_bytes for kind, _, hb in events
-                        if kind == "out")
-        in_bytes = sum(hb.n_bytes for kind, _, hb in events
-                       if kind == "in")
-        if self._stager is not None:
-            self._stager.launch(events)
+        if not events:
+            return 0, 0, 0
+        with span("echo.swap", times, "swap"):
+            out_tokens = sum(hb.n_tokens for kind, _, hb in events
+                             if kind == "out")
+            out_bytes = sum(hb.n_bytes for kind, _, hb in events
+                            if kind == "out")
+            in_bytes = sum(hb.n_bytes for kind, _, hb in events
+                           if kind == "in")
+            if self._stager is not None:
+                self._stager.launch(events)
+                return out_tokens, out_bytes, in_bytes
+            stage = (self.runner is not None
+                     and hasattr(self.runner, "read_block"))
+            for kind, bid, hb in events:
+                if kind == "out":
+                    if stage:
+                        hb.payload = self.runner.read_block(bid)
+                elif stage:
+                    assert hb.payload is not None, (
+                        f"swap-in of block hash {hb.hash} with no staged "
+                        f"payload")
+                    if kind == "in_lazy":
+                        self.runner.write_block_lazy(bid, hb.payload)
+                    else:
+                        self.runner.write_block(bid, hb.payload)
             return out_tokens, out_bytes, in_bytes
-        stage = self.runner is not None and hasattr(self.runner, "read_block")
-        for kind, bid, hb in events:
-            if kind == "out":
-                if stage:
-                    hb.payload = self.runner.read_block(bid)
-            elif stage:
-                assert hb.payload is not None, \
-                    f"swap-in of block hash {hb.hash} with no staged payload"
-                if kind == "in_lazy":
-                    self.runner.write_block_lazy(bid, hb.payload)
-                else:
-                    self.runner.write_block(bid, hb.payload)
-        return out_tokens, out_bytes, in_bytes
 
     def _fence(self, bids: Iterable[int]) -> None:
         """Complete in-flight staging on the blocks a runner call is about
@@ -652,17 +698,23 @@ class EchoEngine:
                 "EchoEngine.step() re-entered while an iteration is in "
                 "flight — the engine must have exactly one driver")
         try:
-            return self._step_impl()
+            self._n_steps += 1
+            with step_span("echo.step", self._n_steps):
+                return self._step_impl()
         finally:
             self._step_lock.release()
 
     def _step_impl(self) -> Optional[IterationRecord]:
+        times = StepTimes(t_start=time.perf_counter())
         self._pull_arrivals()
         tsched = time.perf_counter()
-        plan = self.scheduler.schedule(self.now)
+        plan = self.scheduler.schedule(self.now)        # span echo.sched
+        times.schedule = time.perf_counter() - tsched
+        for req in plan.admitted:
+            if req.wall_admit is None:
+                req.wall_admit = times.t_start
         ts0 = time.perf_counter()
-        schedule_wall = ts0 - tsched
-        out_tok, out_bytes, in_bytes = self._execute_swaps()
+        out_tok, out_bytes, in_bytes = self._execute_swaps(times)
         swap_out_tokens = out_tok + self._pending_swap_out
         swap_out_bytes = out_bytes + self._pending_swap_out_bytes
         swap_in_bytes = in_bytes + self._pending_swap_in_bytes
@@ -698,7 +750,7 @@ class EchoEngine:
 
         st = self._stager
         exposed_pre = st.exposed_wall if st is not None else 0.0
-        t0 = time.perf_counter()
+        t0 = times.t_exec = time.perf_counter()
         offline_tokens = 0
         online_tokens = 0
         emissions = []
@@ -715,11 +767,14 @@ class EchoEngine:
                 # other requests' transfers keep overlapping with this chunk
                 self._fence(req.block_ids)
                 logits = self.runner.prefill_chunk(list(toks), start,
-                                                   req.block_ids, rid=req.rid)
+                                                   req.block_ids, rid=req.rid,
+                                                   times=times)
             else:
                 logits = self._fabricate(req)
             req.computed_tokens = start + chunk
-            self.bm.commit(req, req.full_tokens, self.now)
+            tc = time.perf_counter()
+            self.bm.commit(req, req.full_tokens, self.now)  # echo.kv.commit
+            times.commit += time.perf_counter() - tc
             if req.is_online:
                 online_tokens += chunk
             else:
@@ -738,9 +793,11 @@ class EchoEngine:
                 bts = [r.block_ids for r in decodes]
                 pos = [r.computed_tokens for r in decodes]
                 logits = self.runner.decode(tokens, bts, pos,
-                                            rids=[r.rid for r in decodes])
+                                            rids=[r.rid for r in decodes],
+                                            times=times)
             else:
                 logits = np.stack([self._fabricate(r) for r in decodes])
+            tc = time.perf_counter()
             for i, req in enumerate(decodes):
                 req.computed_tokens += 1
                 self.bm.commit(req, req.full_tokens, self.now)
@@ -749,6 +806,7 @@ class EchoEngine:
                 else:
                     offline_tokens += 1
                 emissions.append((req, logits[i]))
+            times.commit += time.perf_counter() - tc
 
         wall = time.perf_counter() - t0
         spans = [(r.computed_tokens - c, r.computed_tokens)
@@ -808,37 +866,43 @@ class EchoEngine:
             if migrate_transfer > 0.0:
                 self.calibrator.observe_migration(migrate_in_bytes,
                                                   migrate_transfer)
-        for req, lg in emissions:               # tokens arrive at iteration end
-            self._emit(req, lg)
-        for req in plan.preempted:
-            for l in self.listeners:
-                l.on_preempt(req, self.now)
-        if swap_out_tokens:
-            for l in self.listeners:
-                l.on_swap_out(swap_out_tokens, self.now)
-        for req, n in plan.swap_ins:
-            for l in self.listeners:
-                l.on_swap_in(req, n, self.now)
-        if swap_transfer > 0.0:
-            for l in self.listeners:
-                l.on_swap_overlap(swap_transfer, swap_exposed, self.now)
+        # ---- tokens arrive at iteration end (greedy: argmax of the logits)
+        with span("echo.argmax", times, "argmax"):
+            toks_out = [int(np.argmax(lg)) for _, lg in emissions]
+        with span("echo.emit", times, "emit"):
+            for (req, _), tok in zip(emissions, toks_out):
+                self._emit(req, tok)
+            for req in plan.preempted:
+                for l in self.listeners:
+                    l.on_preempt(req, self.now)
+            if swap_out_tokens:
+                for l in self.listeners:
+                    l.on_swap_out(swap_out_tokens, self.now)
+            for req, n in plan.swap_ins:
+                for l in self.listeners:
+                    l.on_swap_in(req, n, self.now)
+            if swap_transfer > 0.0:
+                for l in self.listeners:
+                    l.on_swap_overlap(swap_transfer, swap_exposed, self.now)
 
         # ---- estimator feedback + threshold update (§5.3)
-        online_kv = self._online_kv_tokens()
-        self.mem_pred.observe(self.now, online_kv)
-        if self.policy.task_aware_kv:
-            self.bm.threshold_blocks = self.mem_pred.threshold_blocks(
-                self.bm.num_blocks, self.bm.block_size, online_kv,
-                self.bm.clean_evictable_count())
-            if self.bm.host is not None:
-                # host-tier headroom for the predicted burst's swap-outs,
-                # plus the slots whose payloads are still staging in flight
-                self.bm.host.reserve = self.mem_pred.host_reserve_blocks(
-                    self.bm.block_size, online_kv,
-                    cap_blocks=self.bm.host.capacity,
-                    inflight_blocks=(st.inflight_blocks()
-                                     if st is not None else 0),
-                    io=self.io)
+        with span("echo.kv.threshold", times, "threshold"):
+            online_kv = self._online_kv_tokens()
+            self.mem_pred.observe(self.now, online_kv)
+            if self.policy.task_aware_kv:
+                self.bm.threshold_blocks = self.mem_pred.threshold_blocks(
+                    self.bm.num_blocks, self.bm.block_size, online_kv,
+                    self.bm.clean_evictable_count())
+                if self.bm.host is not None:
+                    # host-tier headroom for the predicted burst's
+                    # swap-outs, plus the slots whose payloads are still
+                    # staging in flight
+                    self.bm.host.reserve = self.mem_pred.host_reserve_blocks(
+                        self.bm.block_size, online_kv,
+                        cap_blocks=self.bm.host.capacity,
+                        inflight_blocks=(st.inflight_blocks()
+                                         if st is not None else 0),
+                        io=self.io)
         t_start = self.now - iter_time
         rec = IterationRecord(
             t=self.now,
@@ -849,7 +913,6 @@ class EchoEngine:
             iter_time=iter_time,
             offline_tokens=offline_tokens,
             online_tokens=online_tokens,
-            usage=self.bm.usage_breakdown(),
             hit_rate=self.bm.metrics.hit_rate,
             threshold_blocks=self.bm.threshold_blocks,
             swap_in_tokens=swap_in_tokens,
@@ -862,21 +925,22 @@ class EchoEngine:
             migrate_in_bytes=migrate_in_bytes,
         )
         self.stats.iterations.append(rec)
+        times.t_end = time.perf_counter()
         base_hook = EngineListener.on_iteration
         detailed = [l for l in self.listeners
                     if type(l).on_iteration is not base_hook]
         if detailed:
-            detail = IterationDetail(
-                t_start=t_start, t_end=self.now,
-                schedule_wall=schedule_wall,
-                compute_time=compute_time,
-                predicted_time=plan.est_time,
-                admitted=plan.admitted,
-                prefill_spans=[(r, s, e) for (r, _), (s, e)
-                               in zip(plan.prefills, spans)],
-                decodes=decodes)
-            for l in detailed:
-                l.on_iteration(rec, detail)
+            with span("echo.observe", times, "observe"):
+                detail = IterationDetail(
+                    t_start=t_start, t_end=self.now,
+                    compute_time=compute_time,
+                    predicted_time=plan.est_time,
+                    admitted=plan.admitted,
+                    prefill_spans=[(r, s, e) for (r, _), (s, e)
+                                   in zip(plan.prefills, spans)],
+                    decodes=decodes, times=times)
+                for l in detailed:
+                    l.on_iteration(rec, detail)
         return rec
 
     # ------------------------------------------------------------- loops

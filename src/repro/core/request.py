@@ -55,6 +55,13 @@ class Request:
     first_scheduled_time: Optional[float] = None   # first batch admission
     finish_time: Optional[float] = None
     token_times: List[float] = field(default_factory=list)
+    # the same journey on time.perf_counter (the profiler's host clock), for
+    # the front-door wait: submitted at the front door, drained from its
+    # intake into the engine, and the start of the step that first admitted
+    # it (the engine-clock stamps above drive the scheduler)
+    wall_submit: Optional[float] = None
+    wall_intake: Optional[float] = None
+    wall_admit: Optional[float] = None
 
     # ------------------------------------------------------------- helpers
     @property

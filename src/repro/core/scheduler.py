@@ -20,6 +20,7 @@ from repro.core.estimator import TimeModel
 from repro.core.policies import PolicyConfig
 from repro.core.radix_pool import OfflinePool
 from repro.core.request import Request, RequestState, TaskType
+from repro.obs.spans import spanned
 
 
 @dataclass
@@ -364,6 +365,7 @@ class Scheduler:
         return self._plan_time(spans, dlens, self._swap_in_bytes(plan))
 
     # ------------------------------------------------------------- schedule
+    @spanned("echo.sched")
     def schedule(self, now: float) -> Plan:
         plan = Plan()
 

@@ -20,6 +20,7 @@ from repro.models import transformer as tfm
 from repro.models.common import rms_norm, rope_angles, swiglu
 from repro.models.model import Model
 from repro.models.moe import moe_apply
+from repro.obs.spans import span
 
 
 def _write_pages(pages, flat_idx, new_k):
@@ -276,32 +277,58 @@ class PagedRunner:
         return self.io.block_bytes(n_tokens)
 
     # ------------------------------------------------------------- API
+    # Each call is one profiler span with the input copies, the jitted
+    # call, the wait for the device and the logits copy to the host as
+    # spans inside it; given the step's ``StepTimes`` (``times``) their
+    # wall seconds, the launch and the sync are counted there too.
     def prefill_chunk(self, token_chunk: Sequence[int], ctx_len: int,
                       block_table: Sequence[int],
-                      rid: Optional[int] = None) -> np.ndarray:
-        sc = self.chunk_size
-        toks = np.zeros((sc,), np.int32)
-        toks[: len(token_chunk)] = token_chunk
-        bt = np.zeros((self.max_pages,), np.int32)
-        bt[: len(block_table)] = block_table
-        logits, self.pages = self._prefill_jit(
-            self.params, jnp.asarray(toks), jnp.int32(ctx_len),
-            jnp.int32(len(token_chunk)), jnp.asarray(bt), self.pages)
-        return np.asarray(logits)
+                      rid: Optional[int] = None, times=None) -> np.ndarray:
+        with span("echo.runner.prefill", rid=-1 if rid is None else rid):
+            with span("echo.runner.prep", times, "prep"):
+                sc = self.chunk_size
+                toks = np.zeros((sc,), np.int32)
+                toks[: len(token_chunk)] = token_chunk
+                bt = np.zeros((self.max_pages,), np.int32)
+                bt[: len(block_table)] = block_table
+                args = (jnp.asarray(toks), jnp.int32(ctx_len),
+                        jnp.int32(len(token_chunk)), jnp.asarray(bt))
+            with span("echo.runner.launch", times, "launch"):
+                logits, self.pages = self._prefill_jit(self.params, *args,
+                                                       self.pages)
+            return self._fetch(logits, times)
 
     def decode(self, tokens: Sequence[int], block_tables: List[Sequence[int]],
                pos: Sequence[int],
-               rids: Optional[Sequence[int]] = None) -> np.ndarray:
+               rids: Optional[Sequence[int]] = None,
+               times=None) -> np.ndarray:
         b = len(tokens)
-        bpad = 1 << (b - 1).bit_length() if b > 1 else 1
-        toks = np.zeros((bpad,), np.int32)
-        toks[:b] = tokens
-        bts = np.zeros((bpad, self.max_pages), np.int32)
-        for i, bt in enumerate(block_tables):
-            bts[i, : len(bt)] = bt
-        ps = np.full((bpad,), -1, np.int32)   # -1 marks padded rows (no write)
-        ps[:b] = pos
-        logits, self.pages = self._decode_jit(
-            self.params, jnp.asarray(toks), jnp.asarray(bts),
-            jnp.asarray(ps), self.pages)
-        return np.asarray(logits[:b])
+        with span("echo.runner.decode", rows=b):
+            with span("echo.runner.prep", times, "prep"):
+                bpad = 1 << (b - 1).bit_length() if b > 1 else 1
+                toks = np.zeros((bpad,), np.int32)
+                toks[:b] = tokens
+                bts = np.zeros((bpad, self.max_pages), np.int32)
+                for i, bt in enumerate(block_tables):
+                    bts[i, : len(bt)] = bt
+                ps = np.full((bpad,), -1, np.int32)   # -1: padded, no write
+                ps[:b] = pos
+                args = (jnp.asarray(toks), jnp.asarray(bts), jnp.asarray(ps))
+            with span("echo.runner.launch", times, "launch"):
+                logits, self.pages = self._decode_jit(self.params, *args,
+                                                      self.pages)
+                logits = logits[:b]
+            return self._fetch(logits, times)
+
+    @staticmethod
+    def _fetch(logits, times) -> np.ndarray:
+        """Wait for the program that produced ``logits``, then copy them to
+        the host."""
+        with span("echo.runner.wait", times, "wait"):
+            jax.block_until_ready(logits)
+        with span("echo.runner.fetch", times, "fetch"):
+            out = np.asarray(logits)
+        if times is not None:
+            times.n_launches += 1
+            times.n_syncs += 1
+        return out

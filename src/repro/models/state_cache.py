@@ -20,6 +20,7 @@ from repro.models import transformer as tfm
 from repro.models.common import rms_norm
 from repro.models.model import Model
 from repro.models.ssm import ssm_context
+from repro.obs.spans import spanned
 
 
 class StateRunner:
@@ -120,8 +121,12 @@ class StateRunner:
         return fn
 
     # ------------------------------------------------------------- API
+    # ``times`` (the engine's per-step counters) is accepted and left at
+    # zero: this runner's calls are one span each, not split by phase
+    @spanned("echo.runner.prefill")
     def prefill_chunk(self, token_chunk: Sequence[int], ctx_len: int,
-                      block_table: Sequence[int], rid: Optional[int] = None):
+                      block_table: Sequence[int], rid: Optional[int] = None,
+                      times=None):
         bs = self.block_size
         assert ctx_len % bs == 0, "resume points are block-aligned"
         if rid in self.live and self._live_pos.get(rid) == ctx_len:
@@ -157,8 +162,10 @@ class StateRunner:
         self._live_pos[rid] = ctx_len + len(toks)
         return np.asarray(logits)
 
+    @spanned("echo.runner.decode")
     def decode(self, tokens: Sequence[int], block_tables: List[Sequence[int]],
-               pos: Sequence[int], rids: Optional[Sequence[int]] = None):
+               pos: Sequence[int], rids: Optional[Sequence[int]] = None,
+               times=None):
         bs = self.block_size
         out = np.zeros((len(tokens), self.model.cfg.vocab_size), np.float32)
         for i, (t, bt, p, rid) in enumerate(zip(tokens, block_tables, pos, rids)):

@@ -15,6 +15,7 @@ executes the ``repro.obs`` package init. The bus is duck-typed
 """
 from __future__ import annotations
 
+import time
 from typing import List, Tuple
 
 from repro.core.engine import (EchoEngine, EngineListener, IterationDetail,
@@ -260,8 +261,9 @@ class RTProbe:
     from submit to first token (``rt_ttft_wall_seconds``) and per token
     after it — via ``AsyncEchoEngine.on_request_done``, which fires on the
     event-loop thread at every handle's terminal transition. With a tracer
-    it draws one span per connection at ``RT_PID`` (serving-clock
-    timeline): submit-to-terminal, first-token instant inside it.
+    it draws one span per connection at ``RT_PID`` on
+    ``time.perf_counter``: submit-to-terminal, first-token instant inside
+    it.
 
     Duck-typed against the engine (``on_request_done``/``stats``/
     ``live_requests``) for the same import-discipline reason as the bus:
@@ -311,15 +313,18 @@ class RTProbe:
         if self.tracer is not None:
             from repro.obs.trace import TID_REQ_BASE
             tid = TID_REQ_BASE + handle.rid
+            # on time.perf_counter, the clock of a wall-clock engine's
+            # tracks: from the front-door submit to now (the terminal)
+            t0 = handle.request.wall_submit
             self.tracer.set_thread(self._rt_pid, tid, f"conn r{handle.rid}")
             self.tracer.span(
                 self._rt_pid, tid, f"r{handle.rid} {status}",
-                handle.t_submit_wall, lat or 0.0,
+                t0, time.perf_counter() - t0,
                 args={"tokens": handle.n_tokens,
                       "ttft_wall": ttft, "tpot_wall": tpot})
-            if handle.t_first_token_wall is not None:
+            if ttft is not None:
                 self.tracer.instant(self._rt_pid, tid, "first_token",
-                                    handle.t_first_token_wall)
+                                    t0 + ttft)
 
 
 def instrument_rt(rt, registry: MetricsRegistry, tracer=None) -> RTProbe:

@@ -2,9 +2,16 @@
 
 ``Tracer`` is a bounded ring buffer of trace events exported as Chrome
 Trace Event JSON (the ``traceEvents`` array format) — load the file at
-https://ui.perfetto.dev or chrome://tracing. The timeline is the engine's
-clock (virtual seconds on the simulator paths, wall seconds otherwise)
-mapped to microseconds.
+https://ui.perfetto.dev or chrome://tracing. A file has one clock, mapped
+to microseconds: a wall-clock engine's tracks are stamped from its steps'
+own ``time.perf_counter`` stamps (``StepTimes``), the front door's from
+its requests' (``Request.wall_submit``), so a connection's span encloses
+its request's engine-side spans; a virtual-clock engine's tracks keep the
+engine's clock (virtual seconds).
+
+These are the engine's own records, drawn after each step. The profiler's
+trace (``jax.profiler.start_trace``) holds the program's ``echo.*`` host
+spans (``repro.obs.spans``) beside the device's ops instead.
 
 Track layout (one Perfetto "process" per replica):
 
@@ -14,9 +21,8 @@ Track layout (one Perfetto "process" per replica):
     tid 3      swap copy-stream — PCIe transfer spans + swap-out instants
     tid 16+rid one track per request: queued span, prefill chunk spans,
                decode spans, preempt/swap-in instants, parked spans
-  pid 9997     rt frontdoor   — per-connection wall-clock spans (submit to
-               terminal, first-token instant); NOTE this pid's timeline is
-               the *serving* clock, the engine pids' is the backend clock
+  pid 9997     rt frontdoor   — per-connection spans (submit to terminal,
+               first-token instant) on ``time.perf_counter``
   pid 9998     service        — admission shed/abort instants
   pid 9999     router         — cluster dispatch/steal instants
 
@@ -29,6 +35,7 @@ engine skips detail construction entirely when no listener overrides
 from __future__ import annotations
 
 import json
+import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
@@ -103,7 +110,7 @@ class Tracer:
         self.set_thread(pid, TID_SCHEDULE, "schedule")
         self.set_thread(pid, TID_KERNEL, "kernel")
         self.set_thread(pid, TID_SWAP, "swap copy-stream")
-        lt = _EngineTracer(self, pid)
+        lt = _EngineTracer(self, pid, wall=engine.clock != "virtual")
         engine.listeners.append(lt)
         self._engine_tracers.append(lt)
         return lt
@@ -146,13 +153,19 @@ class Tracer:
         self.set_process(SERVICE_PID, "service")
         self.set_thread(SERVICE_PID, 1, "admission")
         bus = service.events
+        backend = service.backend
+        engines = backend.engines() if hasattr(backend, "engines") \
+            else [backend]
+        wall = all(e.clock != "virtual" for e in engines)
+
+        def _now():
+            return time.perf_counter() if wall else backend.now()
 
         def _shed(handle):
-            self.instant(SERVICE_PID, 1, "shed", service.backend.now(),
-                         {"rid": handle.rid})
+            self.instant(SERVICE_PID, 1, "shed", _now(), {"rid": handle.rid})
 
         def _abort(handle):
-            self.instant(SERVICE_PID, 1, "abort", service.backend.now(),
+            self.instant(SERVICE_PID, 1, "abort", _now(),
                          {"rid": handle.rid})
 
         bus.subscribe("shed", _shed)
@@ -205,11 +218,18 @@ class _EngineTracer(EngineListener):
     so each request costs O(transitions) events, not O(tokens): a queued
     span from arrival to admission, per-iteration prefill chunk spans, one
     decode span per contiguous decode residency, and parked spans between
-    preemption and re-admission."""
+    preemption and re-admission.
 
-    def __init__(self, tracer: Tracer, pid: int):
+    With ``wall`` (a wall-clock engine) every track is on
+    ``time.perf_counter``: steps from their ``StepTimes`` stamps, events
+    at the moment the engine reports them, a request's queued span from
+    its front-door intake (none for a request submitted to the engine
+    directly)."""
+
+    def __init__(self, tracer: Tracer, pid: int, wall: bool = False):
         self.tr = tracer
         self.pid = pid
+        self.wall = wall
         self._phase: Dict[int, Tuple[str, float]] = {}
         self._named: set = set()
         self.preempted: set = set()
@@ -224,6 +244,11 @@ class _EngineTracer(EngineListener):
                                f"req {req.rid} ({req.task_type.value})")
         return tid
 
+    def _now(self, t: float) -> float:
+        """An event the engine stamped ``t`` on its own clock, on this
+        track's clock."""
+        return time.perf_counter() if self.wall else t
+
     def _close_phase(self, req: Request, t: float) -> None:
         entry = self._phase.pop(req.rid, None)
         if entry is None:
@@ -236,13 +261,18 @@ class _EngineTracer(EngineListener):
     def on_iteration(self, rec: IterationRecord,
                      detail: IterationDetail) -> None:
         tr, pid = self.tr, self.pid
-        t0, t1 = detail.t_start, detail.t_end
+        if self.wall:
+            st = detail.times
+            t0, t1, t_exec = st.t_start, st.t_end, st.t_exec
+        else:
+            t0, t1 = detail.t_start, detail.t_end
+            t_exec = t0
         if detail.schedule_wall > 0:
             tr.span(pid, TID_SCHEDULE, "schedule", t0, detail.schedule_wall,
                     {"n_prefill": rec.n_prefill, "n_decode": rec.n_decode})
         rel = (detail.predicted_time - rec.iter_time) \
             / max(rec.iter_time, 1e-12)
-        tr.span(pid, TID_KERNEL, "exec", t0, detail.compute_time,
+        tr.span(pid, TID_KERNEL, "exec", t_exec, detail.compute_time,
                 {"iter_time": rec.iter_time,
                  "predicted": detail.predicted_time,
                  "rel_err": rel,
@@ -256,9 +286,10 @@ class _EngineTracer(EngineListener):
         for req in detail.admitted:
             entry = self._phase.get(req.rid)
             if entry is None:          # fresh: queued since arrival
-                if t0 > req.arrival_time:
+                t_arr = req.wall_intake if self.wall else req.arrival_time
+                if t_arr is not None and t0 > t_arr:
                     self.tr.span(pid, self._req_tid(req), "queued",
-                                 req.arrival_time, t0 - req.arrival_time)
+                                 t_arr, t0 - t_arr)
             else:                      # parked (or re-queued): close it
                 self._close_phase(req, t0)
         for req, start, end in detail.prefill_spans:
@@ -271,6 +302,7 @@ class _EngineTracer(EngineListener):
                 self._phase[req.rid] = ("decode", t0)
 
     def on_preempt(self, req: Request, t: float) -> None:
+        t = self._now(t)
         self._close_phase(req, t)
         self.preempted.add(req.rid)
         self.tr.instant(self.pid, self._req_tid(req), "preempt", t,
@@ -278,6 +310,7 @@ class _EngineTracer(EngineListener):
         self._phase[req.rid] = ("parked", t)
 
     def on_finish(self, req: Request, t: float) -> None:
+        t = self._now(t)
         self._close_phase(req, t)
         self.tr.instant(self.pid, self._req_tid(req), "finish", t,
                         {"n_output": req.n_output,
@@ -285,9 +318,9 @@ class _EngineTracer(EngineListener):
 
     def on_swap_in(self, req: Request, n_tokens: int, t: float) -> None:
         self.swapped.add(req.rid)
-        self.tr.instant(self.pid, self._req_tid(req), "swap-in", t,
-                        {"tokens": n_tokens})
+        self.tr.instant(self.pid, self._req_tid(req), "swap-in",
+                        self._now(t), {"tokens": n_tokens})
 
     def on_swap_out(self, n_tokens: int, t: float) -> None:
-        self.tr.instant(self.pid, TID_SWAP, "swap-out", t,
+        self.tr.instant(self.pid, TID_SWAP, "swap-out", self._now(t),
                         {"tokens": n_tokens})

@@ -34,11 +34,13 @@ from __future__ import annotations
 import asyncio
 import enum
 import logging
+import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Union
 
 from repro.core.request import Request, TaskType
+from repro.obs.spans import spanned
 from repro.serving.handle import HandleStatus
 from repro.serving.service import EchoService
 from repro.rt.clock import ManualClock, WallClock
@@ -181,6 +183,7 @@ class AsyncEchoEngine:
                              wait: bool = True) -> AsyncRequestHandle:
         """Submit a pre-built ``Request`` (trace replay keeps its
         ``arrival_time``; ``live_arrival`` stamps it at intake)."""
+        req.wall_submit = time.perf_counter()
         handle = AsyncRequestHandle(self, req,
                                     token_queue_cap=self.token_queue_cap,
                                     live_arrival=live_arrival)
@@ -207,6 +210,7 @@ class AsyncEchoEngine:
                           live_arrival: bool = True) -> AsyncRequestHandle:
         """Synchronous non-blocking submit for callers already on the loop
         thread; raises ``SubmitQueueFull`` when saturated."""
+        req.wall_submit = time.perf_counter()
         handle = AsyncRequestHandle(self, req,
                                     token_queue_cap=self.token_queue_cap,
                                     live_arrival=live_arrival)
@@ -328,6 +332,7 @@ class AsyncEchoEngine:
             self._state = RTState.STOPPED
 
     # ------------------------------------------------- loop-thread internals
+    @spanned("echo.rt.intake")
     def _drain_intake(self) -> None:
         while True:
             try:
@@ -337,6 +342,7 @@ class AsyncEchoEngine:
             if handle.done:             # cancelled while still queued
                 continue
             req = handle.request
+            req.wall_intake = time.perf_counter()
             if handle.live_arrival:
                 # wall-clock admission: the request arrives *now* in the
                 # backend's clock domain — the verdict judges live load
@@ -375,6 +381,7 @@ class AsyncEchoEngine:
             self.stats.steps += 1
         return progressed
 
+    @spanned("echo.rt.dispatch")
     def _dispatch(self) -> None:
         now_wall = self.clock.now()
         while self._events:
