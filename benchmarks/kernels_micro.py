@@ -27,7 +27,8 @@ import numpy as np
 
 from repro.kernels import ops, ref
 from repro.kernels.chunked_prefill import chunked_prefill_attention
-from repro.kernels.paged_attention import paged_attention, paged_attention_splitk
+from repro.kernels.paged_attention import (paged_attention,
+                                           paged_decode_attention)
 from repro.kernels.ssd_scan import ssd_scan
 
 RTOL = ATOL = 2e-4
@@ -74,12 +75,12 @@ def rows(strict: bool = True):
     ok = _matches(paged_attention(q, kp, vp, bt, cl, interpret=True), want)
     out.append(("kernel.paged_attention", us, f"pallas_matches={ok}"))
     ok = _matches(
-        paged_attention_splitk(q, kp, vp, bt, cl,
-                               pages_per_split=tune.pages_per_split,
+        paged_decode_attention(q, kp, vp, bt, cl,
+                               pages_per_block=tune.pages_per_block,
                                interpret=True), want)
-    out.append(("kernel.paged_attention_splitk", us, f"pallas_matches={ok}"))
+    out.append(("kernel.paged_decode_attention", us, f"pallas_matches={ok}"))
 
-    # ---- paged decode: long ragged contexts (the split-K target) --------
+    # ---- paged decode: long ragged contexts -----------------------------
     b2, nblk2, p2 = 4, 64, 96
     q2 = jax.random.normal(ks[4], (b2, hq, hd))
     kp2 = jax.random.normal(ks[5], (p2, bs, hkv, hd))
@@ -89,10 +90,10 @@ def rows(strict: bool = True):
     want2 = jit_ref(q2, kp2, vp2, bt2, cl2)
     us = _time(lambda: jit_ref(q2, kp2, vp2, bt2, cl2))
     ok = _matches(
-        paged_attention_splitk(q2, kp2, vp2, bt2, cl2,
-                               pages_per_split=tune.pages_per_split,
+        paged_decode_attention(q2, kp2, vp2, bt2, cl2,
+                               pages_per_block=tune.pages_per_block,
                                interpret=True), want2)
-    out.append(("kernel.paged_attention_splitk_long", us,
+    out.append(("kernel.paged_decode_attention_long", us,
                 f"pallas_matches={ok}"))
 
     # ---- chunked prefill: fused epilogue, tuned tiles -------------------

@@ -5,7 +5,7 @@
 
 One process, three phases:
 
-1. kernels — split-K paged decode (ragged contexts) and chunked prefill
+1. kernels — paged decode (ragged contexts) and chunked prefill
    (prefix + chunk) at Qwen3-4B widths in bf16, each against its ``ref.py``
    oracle computed in float32;
 2. reference — full-width Qwen3-4B (36 layers, d_model 2560, bf16, random
@@ -89,7 +89,7 @@ def compile_cache_dir() -> str:
 
 # ------------------------------------------------------------- kernels
 def kernel_check(cfg, seed: int) -> None:
-    """Pallas split-K decode and chunked prefill at ``cfg``'s widths in
+    """Pallas paged decode and chunked prefill at ``cfg``'s widths in
     bf16 against the float32 ``ref.py`` oracles."""
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
@@ -115,9 +115,10 @@ def kernel_check(cfg, seed: int) -> None:
     vp = jax.random.normal(ks[2], (pages, PAGE_SIZE, hkv, hd), bf)
     bt = jax.random.randint(ks[3], (b, MAX_PAGES_PER_SEQ), 0, pages)
     ctx = jnp.asarray([1, 15, 16, 17, 300, 511, 777, 1024], jnp.int32)
-    got = ops.paged_attention(q, kp, vp, bt, ctx, impl="splitk")
+    got = ops.paged_attention(q, kp, vp, bt, ctx,
+                              impl="paged_decode_attention")
     err = max_err(got, f32_ref(ref.ref_paged_attention, q, kp, vp, bt, ctx))
-    log(f"kernel splitk paged decode  B={b} Hq={hq} Hkv={hkv} hd={hd} "
+    log(f"kernel paged decode         B={b} Hq={hq} Hkv={hkv} hd={hd} "
         f"ctx={ctx.tolist()}: max abs err {err:.3g} vs float32 ref")
 
     t, prefix = MAX_PAGES_PER_SEQ * PAGE_SIZE, 700

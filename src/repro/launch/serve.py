@@ -475,10 +475,12 @@ def main() -> None:
                          f"{TimeModel.HW_PROFILES}, comma-separated to cycle "
                          "profiles over a heterogeneous --replicas fleet")
     ap.add_argument("--attn-impl", default="auto",
-                    choices=["auto", "ref", "pallas", "splitk"],
+                    choices=["auto", "ref", "pallas",
+                             "paged_decode_attention"],
                     help="attention kernel path on the real-model runner: "
-                         "auto = jnp oracle on CPU / split-K Pallas on "
-                         "accelerators (see repro.kernels.ops)")
+                         "auto = jnp oracle on CPU / the live-page Pallas "
+                         "decode kernel on accelerators (see "
+                         "repro.kernels.ops)")
     ap.add_argument("--kernel-profile", default=None,
                     choices=["v5e", "cpu"],
                     help="kernel block-size tuning table (default: resolve "

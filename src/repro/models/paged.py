@@ -114,8 +114,8 @@ class PagedRunner:
         self.max_pages = max_pages_per_seq
         self.chunk_size = chunk_size
         # attention kernel dispatch: "auto" runs the jnp oracles on CPU and
-        # the split-K Pallas path on accelerators; "ref"/"pallas"/"splitk"
-        # force one. kernel_profile picks the block-size tuning table
+        # the Pallas paged_decode_attention path on accelerators;
+        # "ref"/"pallas"/"paged_decode_attention" force one. kernel_profile picks the block-size tuning table
         # (None resolves by device kind — see repro.kernels.ops).
         self.attn_impl = attn_impl
         self.kernel_profile = kernel_profile
@@ -303,7 +303,9 @@ class PagedRunner:
                rids: Optional[Sequence[int]] = None,
                times=None) -> np.ndarray:
         b = len(tokens)
-        with span("echo.runner.decode", rows=b):
+        bs = self.page_size     # live pages: the ones the decode kernel reads
+        pages = sum((p + bs) // bs for p in pos)
+        with span("echo.runner.decode", rows=b, pages=pages):
             with span("echo.runner.prep", times, "prep"):
                 bpad = 1 << (b - 1).bit_length() if b > 1 else 1
                 toks = np.zeros((bpad,), np.int32)

@@ -6,7 +6,8 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.chunked_prefill import chunked_prefill_attention
-from repro.kernels.paged_attention import paged_attention, paged_attention_splitk
+from repro.kernels.paged_attention import (paged_attention,
+                                           paged_decode_attention)
 from repro.kernels.ssd_scan import ssd_scan
 
 
@@ -112,13 +113,15 @@ PAGED_DECODE_CASES = [
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("case", PAGED_DECODE_CASES)
-@pytest.mark.parametrize("pages_per_split", [1, 2, 4])
-def test_paged_attention_splitk_sweep(dtype, case, pages_per_split):
+@pytest.mark.parametrize("pages_per_block", [1, 2, 4])
+def test_paged_attention_splitk_sweep(dtype, case, pages_per_block):
+    """The decode kernel (``paged_decode_attention``, which replaced the
+    split-K schedule) against the oracle, 1-4 pages a block."""
     b, hq, hkv, hd, bs, nblk, ctx_lens = case
     q, kp, vp, bt, cl = _paged_case(b * 7 + hq, b, hq, hkv, hd, bs, nblk,
                                     ctx_lens, dtype)
-    out = paged_attention_splitk(q, kp, vp, bt, cl,
-                                 pages_per_split=pages_per_split,
+    out = paged_decode_attention(q, kp, vp, bt, cl,
+                                 pages_per_block=pages_per_block,
                                  interpret=True)
     want = ref.ref_paged_attention(q, kp, vp, bt, cl)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
@@ -142,14 +145,90 @@ def test_paged_attention_legacy_sweep(dtype, case):
 
 
 def test_paged_attention_splitk_oversized_split():
-    """pages_per_split larger than the whole table degenerates to a single
-    split and must still match."""
+    """A block larger than the whole table degenerates to one block a row
+    and must still match."""
     q, kp, vp, bt, cl = _paged_case(3, 2, 4, 2, 32, 8, 4, [32, 9], jnp.float32)
-    out = paged_attention_splitk(q, kp, vp, bt, cl, pages_per_split=64,
+    out = paged_decode_attention(q, kp, vp, bt, cl, pages_per_block=64,
                                  interpret=True)
     want = ref.ref_paged_attention(q, kp, vp, bt, cl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+def _poison_dead_pages(kp, vp, bt, cl, bs):
+    """NaN in every pool slot no row reads: the tail of each row's last
+    live page, and the pages its table names past that page."""
+    live = np.zeros(kp.shape[:2], bool)
+    for row, ctx in zip(np.asarray(bt), np.asarray(cl)):
+        for t in range(int(ctx)):
+            live[row[t // bs], t % bs] = True
+    dead = jnp.asarray(~live)[:, :, None, None]
+    return jnp.where(dead, jnp.nan, kp), jnp.where(dead, jnp.nan, vp)
+
+
+@pytest.mark.parametrize("pages_per_block", [1, 2, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_never_reads_dead_pages(dtype, pages_per_block):
+    """K and V past each row's ctx hold NaN: the output stays finite and
+    matches the oracle on the clean pool."""
+    b, bs, nblk = 3, 8, 6
+    # distinct pages a row, so no live page is also another row's dead one
+    q, kp, vp, _, cl = _paged_case(5, b, 8, 2, 32, bs, nblk, [9, 30, 17],
+                                   dtype)
+    bt = jnp.arange(b * nblk, dtype=jnp.int32).reshape(b, nblk)[:, ::-1]
+    want = ref.ref_paged_attention(q, kp, vp, bt, cl)
+    kp_nan, vp_nan = _poison_dead_pages(kp, vp, bt, cl, bs)
+    out = paged_decode_attention(q, kp_nan, vp_nan, bt, cl,
+                                 pages_per_block=pages_per_block,
+                                 interpret=True)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), rtol=tol, atol=tol)
+
+
+def test_paged_decode_padded_row_writes_zeros():
+    """A padded row (ctx 0) gives zeros, not NaN, beside live rows that
+    still match; its table points at NaN pages it must not read."""
+    q, kp, vp, _, cl = _paged_case(13, 3, 4, 2, 32, 8, 4, [20, 0, 7],
+                                   jnp.float32)
+    bt = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
+    kp, vp = kp.at[bt[1]].set(jnp.nan), vp.at[bt[1]].set(jnp.nan)
+    want = ref.ref_paged_attention(q, kp, vp, bt, cl)
+    out = np.asarray(paged_decode_attention(q, kp, vp, bt, cl,
+                                            pages_per_block=2,
+                                            interpret=True))
+    np.testing.assert_array_equal(out[1], 0.0)
+    np.testing.assert_allclose(out[[0, 2]], np.asarray(want)[[0, 2]],
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("ctx_lens", [[16, 32], [17, 33], [15, 48]])
+def test_paged_decode_block_edges(ctx_lens):
+    """ctx exactly at a block boundary (2 pages of 8), one token past it
+    and one short of it."""
+    q, kp, vp, bt, cl = _paged_case(17, 2, 4, 2, 32, 8, 6, ctx_lens,
+                                    jnp.float32)
+    out = paged_decode_attention(q, kp, vp, bt, cl, pages_per_block=2,
+                                 interpret=True)
+    want = ref.ref_paged_attention(q, kp, vp, bt, cl)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("hkv", [8, 4], ids=["qwen3-4b", "yi-9b"])
+def test_paged_decode_served_gqa_shapes(hkv):
+    """The served configurations' heads at head_dim 128 in bf16: Qwen3-4B
+    (8 KV heads, group 4) and Yi-9B (4 KV heads, group 8), 32 query
+    heads, on a small pool of 16-token pages."""
+    q, kp, vp, bt, cl = _paged_case(19, 4, 32, hkv, 128, 16, 8,
+                                    [100, 1, 64, 65], jnp.bfloat16)
+    out = paged_decode_attention(q, kp, vp, bt, cl, pages_per_block=4,
+                                 interpret=True)
+    want = ref.ref_paged_attention(q, kp, vp, bt, cl)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -176,20 +255,21 @@ def test_chunked_prefill_nondivisible_sweep(dtype, sc, t, hq, hkv, hd, ctx,
 
 
 def test_ops_dispatch_and_tuning():
-    """ops-layer routing: impl="ref" is the oracle, impl="splitk"/"pallas"
-    agree with it, presets resolve to per-backend tuning tables."""
+    """ops-layer routing: impl="ref" is the oracle,
+    impl="paged_decode_attention"/"pallas" agree with it, presets resolve
+    to per-backend tuning tables."""
     q, kp, vp, bt, cl = _paged_case(11, 2, 8, 2, 32, 8, 4, [32, 11],
                                     jnp.float32)
     want = ops.paged_attention(q, kp, vp, bt, cl, impl="ref")
     np.testing.assert_allclose(
         np.asarray(ref.ref_paged_attention(q, kp, vp, bt, cl)),
         np.asarray(want), rtol=0, atol=0)
-    for impl in ("splitk", "pallas"):
+    for impl in ("paged_decode_attention", "pallas"):
         got = ops.paged_attention(q, kp, vp, bt, cl, impl=impl, preset="cpu")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
-    assert ops.kernel_tuning("v5e").pages_per_split > \
-        ops.kernel_tuning("cpu").pages_per_split
+    assert ops.kernel_tuning("v5e").pages_per_block > \
+        ops.kernel_tuning("cpu").pages_per_block
     assert ops.kernel_tuning(None) == ops.kernel_tuning("cpu")  # CPU backend
     with pytest.raises(ValueError):
         ops.kernel_tuning("tpu9000")

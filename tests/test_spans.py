@@ -117,6 +117,16 @@ def test_runner_spans_nest_inside_the_step(served):
                                          + found["echo.runner.prefill"])
 
 
+def test_decode_span_counts_live_pages(served):
+    """``pages`` on a decode span is the live pages its rows read: at
+    least one a row."""
+    spans = [e for events in served["lines"] for e in events
+             if e[0] == "echo.runner.decode"]
+    assert spans
+    for e in spans:
+        assert e[3]["pages"] >= e[3]["rows"] >= 1, e
+
+
 def test_every_boundary_has_its_span(served):
     names = {e[0] for events in served["lines"] for e in events}
     assert {"echo.rt.intake", "echo.rt.dispatch", "echo.sched",

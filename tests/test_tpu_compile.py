@@ -1,5 +1,6 @@
-"""The served attention kernels compile for one TPU v5e chip at Qwen3-4B
-widths (Hq=32, Hkv=8, head_dim=128, bf16, 16-token pages).
+"""The served attention kernels compile for one TPU v5e chip at the served
+widths (Hq=32, head_dim=128, bf16, 16-token pages; Hkv 8 for Qwen3-4B, 4
+for Yi-9B).
 
 Nothing runs: the chip is described, not attached, and the TPU compiler
 refuses here what it would refuse on the chip (block shapes off the (8, 128)
@@ -16,10 +17,11 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 from repro.kernels.chunked_prefill import chunked_prefill_attention
 from repro.kernels.paged_attention import (paged_attention,
-                                           paged_attention_splitk)
+                                           paged_decode_attention)
 
 HQ, HKV, HD, PAGE = 32, 8, 128, 16
-MAX_RUNNING, MAX_PAGES_PER_SEQ, POOL_PAGES, CHUNK = 64, 64, 1024, 256
+MAX_PAGES_PER_SEQ, POOL_PAGES, CHUNK = 64, 1024, 256
+SERVED_PAGES_PER_SEQ = 256      # the benchmark cells' max_pages_per_seq
 
 
 @pytest.fixture(scope="module")
@@ -46,20 +48,23 @@ def _compile(fn, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("kernel", ["splitk", "legacy"])
-def test_paged_decode_compiles_for_v5e(one_chip, kernel):
-    """The decode batch padded to max_running, against a full pool, with
-    the (64, max_pages_per_seq) block table in scalar-prefetch memory."""
+@pytest.mark.parametrize("batch", [8, 64])
+@pytest.mark.parametrize("hkv", [8, 4], ids=["qwen3-4b", "yi-9b"])
+@pytest.mark.parametrize("kernel", ["paged_decode_attention", "legacy"])
+def test_paged_decode_compiles_for_v5e(one_chip, kernel, hkv, batch):
+    """A decode batch of 8 (the cells' max_running) and of 64 against a
+    full pool, 256 pages a row in scalar-prefetch memory, at the v5e
+    table's pages_per_block: the compiler checks the VMEM budget."""
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     tune = ops.kernel_tuning("v5e")
-    fn = (functools.partial(paged_attention_splitk,
-                            pages_per_split=tune.pages_per_split)
-          if kernel == "splitk" else paged_attention)
-    pool = sds((POOL_PAGES, PAGE, HKV, HD), jnp.bfloat16)
-    _compile(fn, sds((MAX_RUNNING, HQ, HD), jnp.bfloat16), pool, pool,
-             sds((MAX_RUNNING, MAX_PAGES_PER_SEQ), jnp.int32),
-             sds((MAX_RUNNING,), jnp.int32))
+    fn = (functools.partial(paged_decode_attention,
+                            pages_per_block=tune.pages_per_block)
+          if kernel == "paged_decode_attention" else paged_attention)
+    pool = sds((POOL_PAGES, PAGE, hkv, HD), jnp.bfloat16)
+    _compile(fn, sds((batch, HQ, HD), jnp.bfloat16), pool, pool,
+             sds((batch, SERVED_PAGES_PER_SEQ), jnp.int32),
+             sds((batch,), jnp.int32))
 
 
 def test_chunked_prefill_compiles_for_v5e(one_chip):
@@ -82,7 +87,7 @@ def test_untileable_head_dim_raises_naming_shape(one_chip):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     pool = sds((POOL_PAGES, PAGE, HKV, 64), jnp.bfloat16)
     with pytest.raises(ValueError, match=r"\(1024, 16, 8, 64\)"):
-        jax.jit(paged_attention_splitk).lower(
+        jax.jit(paged_decode_attention).lower(
             sds((8, HQ, 64), jnp.bfloat16), pool, pool,
             sds((8, MAX_PAGES_PER_SEQ), jnp.int32), sds((8,), jnp.int32))
 
