@@ -31,7 +31,7 @@ import weights as weight_gen
 
 from repro.configs.base import ModelConfig
 from repro.core import ECHO, SLO
-from repro.core.engine import EngineListener
+from repro.core.engine import EngineListener, StepTimes
 from repro.launch.serve import build_engine
 from repro.rt import AsyncEchoEngine
 from repro.serving.handle import HandleStatus
@@ -62,15 +62,9 @@ class Clock:
 
 
 def model_config(cfg: dict) -> ModelConfig:
-    """The program's ``ModelConfig`` for a configuration file."""
-    return ModelConfig(
-        name=cfg["name"], family="dense", source=cfg["source"],
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        vocab_size=cfg["vocab_size"], num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], qk_norm=cfg["qk_norm"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"], dtype=cfg["torch_dtype"])
+    """The program's ``ModelConfig`` for a configuration file, from its
+    family (``families/<family>.py``)."""
+    return common.family(cfg).program_config(cfg)
 
 
 # ------------------------------------------------------------- counters
@@ -85,6 +79,11 @@ class IterRow:
     recomputed: int                     # tokens re-prefilled after preemption
     offline_first: List[Tuple[int, int]]  # (cached, prompt) tokens of each
     #                                       offline request's first chunk
+    times: Optional[StepTimes] = None   # the step's ``IterationDetail.times``
+    online_admits: List[Tuple[float, float, float]] = field(
+        default_factory=list)           # (wall_submit, wall_intake,
+    #   wall_admit) perf_counter stamps of the online requests this step
+    #   admits for the first time
 
 
 class Counters(EngineListener):
@@ -103,11 +102,17 @@ class Counters(EngineListener):
                 first.append((s, r.prompt_len))
             rec_tokens += r.recomputed_tokens - self._recomputed.get(r.rid, 0)
             self._recomputed[r.rid] = r.recomputed_tokens
+        t0 = detail.times.t_start
+        admits = [(r.wall_submit, r.wall_intake, r.wall_admit)
+                  for r in detail.admitted
+                  if r.is_online and r.wall_admit == t0
+                  and r.wall_submit is not None]
         self.rows.append(IterRow(
             self.clock.now(), detail.schedule_wall, detail.compute_time,
             detail.predicted_time,
             [(s, e - s) for _, s, e in detail.prefill_spans],
-            [r.total_len for r in detail.decodes], rec_tokens, first))
+            [r.total_len for r in detail.decodes], rec_tokens, first,
+            detail.times, admits))
 
 
 class StepHook:
@@ -335,13 +340,16 @@ def end_to_end(clients: List[Client], window: Tuple[float, float],
 @dataclass
 class ReadContext:
     """What a per-layer reader reads: the configuration file, the chip's
-    peaks, the window's host counters, and (traced runs) the device trace
-    with the host counters of the traced steps."""
+    peaks, the window's host counters, (traced runs) the device trace
+    with the host counters of the traced steps, and the run's own
+    end-to-end readings (``end_to_end``), for a cell that reports one of
+    them per layer."""
     dims: dict
     peak: dict
     rows: List[IterRow]
     trace: Optional[tracing.TraceSummary] = None
     traced_rows: List[IterRow] = field(default_factory=list)
+    e2e: Dict[str, float] = field(default_factory=dict)
 
 
 # ------------------------------------------------------------- run
@@ -385,7 +393,7 @@ def serve_engine(cfg: dict, seed: int, fault=None):
                          runner.params)
     runner.params = None
     gc.collect()
-    weights = weight_gen.make_weights(specs, seed)
+    weights = weight_gen.make_weights(specs, seed, common.family(cfg))
     runner.params = weights
     if fault is not None:
         fault(engine)
@@ -493,7 +501,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         shutil.rmtree(trace_dir, ignore_errors=True)
         summary = tracing.reduce(compact)
         del compact
-    ctx = ReadContext(cfg, peak, rows, summary, traced_rows)
+    ctx = ReadContext(cfg, peak, rows, summary, traced_rows, metrics)
 
     # ---- free the program's state, then compare with the reference
     finished = [(i, c.prompt, c.tokens) for i, c in enumerate(clients)
@@ -504,7 +512,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     del rt, engine, runner, hook, counters
     gc.collect()
     t_ref = time.perf_counter()
-    view = reference.dense_view(weights, cfg)
+    view = reference.view(weights, cfg)
     pick = (reference.sample(finished, seed, min_tokens=SAMPLE_MIN_TOKENS,
                              max_requests=SAMPLE_MAX_REQUESTS)
             if compare else [])

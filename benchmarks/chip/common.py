@@ -11,17 +11,24 @@ per-layer metric lives in a file of its own, found from the names in
   generator in ``traffic.py``;
 - ``metrics/<metric>.py``: a reader ``read(ctx)`` that returns the metric
   or ``None`` when it finds nothing to read;
+- ``families/<family>.py``: what belongs to one family of blocks, named by
+  a configuration file's ``family`` key (``dense`` where it has none): the
+  program's ``ModelConfig``, the weight rules, the reference's layer and
+  the weights a token multiplies by (``families/dense.py`` lists them);
 - ``peaks.json``: peak rates keyed by ``device_kind``.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+FAMILIES = HERE / "families"      # where ``family`` looks
 
 
 def load_json(path: Path) -> dict:
@@ -57,6 +64,30 @@ def metric_reader(name: str) -> Callable:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+@functools.lru_cache(maxsize=None)
+def _load_family(path: Path) -> ModuleType:
+    """One module object per file, so that the functions it gives (the
+    reference's jitted layer among them) are the same on every call."""
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_family_" + path.stem.replace("-", "_").replace(".", "_"),
+        path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(cfg: dict) -> ModuleType:
+    """The module ``families/<family>.py`` of a configuration, by its
+    ``family`` key (``dense`` where it has none); an unknown family is an
+    error that names the missing file."""
+    name = cfg.get("family", "dense")
+    path = FAMILIES / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"configuration {cfg.get('name')!r} is of family "
+                       f"{name!r}, and there is no {path}")
+    return _load_family(path)
 
 
 def metrics_of(cell_name: str, kind: str) -> Dict[str, dict]:

@@ -13,16 +13,20 @@ program execution; ``host`` the benchmark's own ``bench.*`` spans
 (``jax.profiler.TraceAnnotation`` around the engine step, the scheduler,
 the runner's calls, the front door's worker hop, the KV commit).
 
-Device work is attributed by the host span open around it, not by
-program or kernel names: every runner call ends by copying its logits to
+Device work is attributed to a runner call by the host span open around
+it, not by program names: every runner call ends by copying its logits to
 the host, so the programs of one ``bench.decode`` span are that decode
-call's, and the custom calls among their ops are its attention kernel.
+call's. Within a kind of call, custom-call time is also kept by kernel
+name (the HLO op name up to its last ``.<n>``: ``paged_decode_attention``,
+``chunked_prefill_attention``), so that a kernel's reader reads its own
+time and no other kernel's.
 """
 from __future__ import annotations
 
 import bisect
 import glob
 import os
+import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -36,7 +40,8 @@ class TraceSummary:
     busy_s: float                         # union of op intervals, mean over chips
     calls: Dict[str, int]                 # runner calls per kind
     program_s: Dict[str, float]           # device module time per kind
-    kernel_s: Dict[str, float]            # custom-call time per kind
+    kernels: Dict[str, Dict[str, float]]  # custom-call time per kind, by
+    #                                       kernel name
     top_ops: List[Tuple[str, float]]      # most device time, by leaf op
     idle_by_host: List[Tuple[str, float]]  # idle time, by innermost host span
     n_devices: int = 1
@@ -51,6 +56,11 @@ def _custom(name: str) -> bool:
 def _short(name: str) -> str:
     """``%fusion.12 = bf16[...] fusion(...)`` -> ``fusion.12``."""
     return name.split(" = ", 1)[0].lstrip("%")
+
+
+def kernel_name(op: str) -> str:
+    """``paged_decode_attention.9`` -> ``paged_decode_attention``."""
+    return re.sub(r"\.\d+$", "", op)
 
 
 def _parents(ops) -> set:
@@ -127,9 +137,9 @@ def _innermost(spans: List[Tuple[str, int, int]], t: float) -> str:
 
 
 def reduce(compact: dict, top: int = 10) -> Optional[TraceSummary]:
-    """Window, busy time, per-kind runner program and kernel time, top
-    device ops and idle time by host span. ``None`` when the trace holds
-    no traced step or no device op."""
+    """Window, busy time, per-kind runner program time and kernel time by
+    name, top device ops and idle time by host span. ``None`` when the
+    trace holds no traced step or no device op."""
     host = [(n, s, s + d) for n, s, d in compact["host"]]
     steps = [(s, e) for n, s, e in host if n == "bench.step"]
     if not steps or not compact["devices"]:
@@ -140,7 +150,7 @@ def reduce(compact: dict, top: int = 10) -> Optional[TraceSummary]:
     calls = {k: sum(1 for n, s, e in host if n == v and lo <= s < hi)
              for k, v in RUNNER_SPANS.items()}
     prog = {k: 0.0 for k in RUNNER_SPANS}
-    kern = {k: 0.0 for k in RUNNER_SPANS}
+    by_name: Dict[str, Dict[str, float]] = {k: {} for k in RUNNER_SPANS}
     kind_spans = {k: _union([(s, e) for n, s, e in host if n == v])
                   for k, v in RUNNER_SPANS.items()}
     edges = sorted({t for _, s, e in host for t in (s, e)})
@@ -164,7 +174,8 @@ def reduce(compact: dict, top: int = 10) -> Optional[TraceSummary]:
                 op_time[n] = op_time.get(n, 0.0) + d
             k = kind_of((s + e) / 2)
             if k is not None and c:
-                kern[k] += d
+                name = kernel_name(n)
+                by_name[k][name] = by_name[k].get(name, 0.0) + d
         for n, s, d in dev["modules"]:
             k = kind_of(s + d / 2)
             if k is not None and lo <= s < hi:
@@ -183,7 +194,7 @@ def reduce(compact: dict, top: int = 10) -> Optional[TraceSummary]:
     n_dev = len(compact["devices"])
     return TraceSummary(
         window_s=window / 1e9, busy_s=busy_total / n_dev, calls=calls,
-        program_s=prog, kernel_s=kern,
+        program_s=prog, kernels=by_name,
         top_ops=sorted(op_time.items(), key=lambda x: -x[1])[:top],
         idle_by_host=sorted(idle.items(), key=lambda x: -x[1])[:top],
         n_devices=n_dev)

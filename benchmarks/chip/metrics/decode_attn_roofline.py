@@ -1,13 +1,16 @@
 """Decode attention kernel's share of its roofline, %: the least time the
 chip needs for the live-context KV bytes and FLOPs of the traced decode
 calls (context lengths from the engine's iteration hook), over the device
-time of the custom calls (the Pallas kernel) inside those calls."""
+time of the kernel named ``paged_decode_attention`` inside those calls."""
 import roofline
+
+KERNEL = "paged_decode_attention"
 
 
 def read(ctx):
     t = ctx.trace
-    if t is None or t.kernel_s["decode"] <= 0:
+    kernel_s = t.kernels["decode"].get(KERNEL, 0.0) if t is not None else 0.0
+    if kernel_s <= 0:
         return None
     least = 0.0
     for r in ctx.traced_rows:
@@ -16,4 +19,4 @@ def read(ctx):
             least += roofline.least_time(f, b, ctx.peak)
     if least <= 0:
         return None
-    return 100.0 * least / t.kernel_s["decode"]
+    return 100.0 * least / kernel_s

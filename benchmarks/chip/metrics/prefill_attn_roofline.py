@@ -1,13 +1,16 @@
 """Chunked-prefill attention kernel's share of its roofline, %: the least
 time the chip needs for the live context's KV bytes and causal FLOPs of
-the traced prefill chunks, over the device time of the custom calls (the
-Pallas kernel) inside those calls."""
+the traced prefill chunks, over the device time of the kernel named
+``chunked_prefill_attention`` inside those calls."""
 import roofline
+
+KERNEL = "chunked_prefill_attention"
 
 
 def read(ctx):
     t = ctx.trace
-    if t is None or t.kernel_s["prefill"] <= 0:
+    kernel_s = t.kernels["prefill"].get(KERNEL, 0.0) if t is not None else 0.0
+    if kernel_s <= 0:
         return None
     least = 0.0
     for r in ctx.traced_rows:
@@ -16,4 +19,4 @@ def read(ctx):
             least += roofline.least_time(f, b, ctx.peak)
     if least <= 0:
         return None
-    return 100.0 * least / t.kernel_s["prefill"]
+    return 100.0 * least / kernel_s

@@ -2,14 +2,17 @@
 ``correct``.
 
 The reference is the published decoder in straightforward ``jax.numpy``
-and float32 at ``highest`` matmul precision: token embedding, per layer
-RMSNorm, grouped-query attention with optional per-head RMSNorm of q and k
-(Qwen3), rotary position embedding (rotate-half, HF convention), causal
-softmax, output projection, RMSNorm, SwiGLU MLP, residuals; a final
-RMSNorm and the (tied or untied) output head. It imports nothing of the
-program: it reads the benchmark's own weights (``weights.py``) through the
-layout adapter ``dense_view``. Layers run in a scan, each upcast to float32
-as it is used, so the full-width model fits beside its bfloat16 weights.
+and float32 at ``highest`` matmul precision: token embedding, the layers,
+a final RMSNorm and the (tied or untied) output head. The layers are the
+configuration's family's (``families/<family>.py``: its ``layer`` on the
+residual stream, from the rotary tables, the norm's epsilon and the
+family's static configuration keys in ``Env``); they call the helpers
+here: ``_mm`` (a matmul, fp8-rounded in the control), ``_rms``, ``_rope``
+(rotate-half, HF convention) and ``_attention`` (causal grouped-query
+softmax). It imports nothing of the program: it reads the benchmark's own
+weights (``weights.py``) through the family's layout adapter ``view``.
+Layers run in a scan, each upcast to float32 as it is used, so the
+full-width model fits beside its bfloat16 weights.
 
 The comparison is teacher-forced over a request's prompt and the tokens
 the program served: at each position where a token was served, the gap by
@@ -27,11 +30,13 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import List, Sequence, Tuple
+from typing import List, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import common
 
 HIGHEST = jax.lax.Precision.HIGHEST
 T_BLOCK = 1024           # sequence lengths are padded to a multiple of this
@@ -40,15 +45,18 @@ Q_BLOCK = 512            # attention is computed in blocks of query rows
 E4M3_MAX = 448.0
 
 
-def dense_view(weights, cfg: dict) -> dict:
-    """The benchmark's weights, named by what they are: one scanned
-    segment of identical attention+MLP blocks with stacked parameters."""
-    (seg,) = weights["layers"]
-    (blk,) = seg
-    head = (weights["embed"].T if cfg["tie_word_embeddings"]
-            else weights["unembed"])
-    return {"embed": weights["embed"], "final_ln": weights["final_ln"],
-            "head": head, "layers": blk}
+class Env(NamedTuple):
+    """What a family's ``layer`` reads beside its weights."""
+    cos: object          # rotary tables, (T, head_dim / 2)
+    sin: object
+    eps: float           # RMSNorm epsilon
+    fp8: bool            # the control: matmul operands rounded to fp8
+    cfg: dict            # the static configuration keys (``_cfg_items``)
+
+
+def view(weights, cfg: dict) -> dict:
+    """The benchmark's weights through the family's layout adapter."""
+    return common.family(cfg).view(weights, cfg)
 
 
 def fake_e4m3(x, axis):
@@ -99,8 +107,8 @@ def _attention(q, k, v, fp8):
     return jnp.concatenate(outs, 0).reshape(t, hq, hd)
 
 
-@partial(jax.jit, static_argnames=("cfg_items", "fp8"))
-def _logits(view, tokens, rows, cfg_items, fp8):
+@partial(jax.jit, static_argnames=("layer", "cfg_items", "fp8"))
+def _logits(view, tokens, rows, layer, cfg_items, fp8):
     cfg = dict(cfg_items)
     eps, hd = cfg["rms_norm_eps"], cfg["head_dim"]
     t = tokens.shape[0]
@@ -108,27 +116,13 @@ def _logits(view, tokens, rows, cfg_items, fp8):
     inv = 1.0 / (cfg["rope_theta"] ** (jnp.arange(0, hd, 2, dtype=jnp.float32)
                                        / hd))
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    env = Env(jnp.cos(ang), jnp.sin(ang), eps, fp8, cfg)
 
-    def layer(x, w):
+    def step(x, w):
         w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-        a = w["attn"]
-        h = _rms(x, w["ln1"], eps)
-        q = _mm("td,dhk->thk", h, a["wq"], -1, 0, fp8)
-        k = _mm("td,dhk->thk", h, a["wk"], -1, 0, fp8)
-        v = _mm("td,dhk->thk", h, a["wv"], -1, 0, fp8)
-        if cfg["qk_norm"]:
-            q = _rms(q, a["q_norm"], eps)
-            k = _rms(k, a["k_norm"], eps)
-        o = _attention(_rope(q, cos, sin), _rope(k, cos, sin), v, fp8)
-        x = x + _mm("thk,hkd->td", o, a["wo"], (-2, -1), (0, 1), fp8)
-        h = _rms(x, w["ln2"], eps)
-        m = w["mlp"]
-        u = (jax.nn.silu(_mm("td,df->tf", h, m["w1"], -1, 0, fp8))
-             * _mm("td,df->tf", h, m["w3"], -1, 0, fp8))
-        return x + _mm("tf,fd->td", u, m["w2"], -1, 0, fp8), None
+        return layer(x, w, env), None
 
-    x, _ = jax.lax.scan(layer, x, view["layers"])
+    x, _ = jax.lax.scan(step, x, view["layers"])
     xr = _rms(x[rows], view["final_ln"].astype(jnp.float32), eps)
     return _mm("td,dv->tv", xr, view["head"].astype(jnp.float32), -1, 0, fp8)
 
@@ -141,7 +135,8 @@ def _gaps(ref, tokens):
 
 
 def _cfg_items(cfg: dict) -> Tuple:
-    keys = ("rms_norm_eps", "head_dim", "rope_theta", "qk_norm")
+    keys = ("rms_norm_eps", "head_dim", "rope_theta"
+            ) + tuple(common.family(cfg).CONFIG_KEYS)
     return tuple((k, cfg[k]) for k in keys)
 
 
@@ -164,13 +159,14 @@ def request_gaps(view, cfg: dict, prompt: Sequence[int],
     t = -(-len(seq) // T_BLOCK) * T_BLOCK
     tokens = jnp.asarray(_pad(seq, t))
     rows = jnp.asarray(_pad(range(len(prompt) - 1, len(seq)), ROWS))
-    items = _cfg_items(cfg)
+    layer, items = common.family(cfg).layer, _cfg_items(cfg)
     ctrl_gap = np.zeros(0)
     with jax.default_matmul_precision("highest"):
-        ref = _logits(view, tokens, rows, items, False)
+        ref = _logits(view, tokens, rows, layer, items, False)
         gap = np.asarray(_gaps(ref, jnp.asarray(_pad(served, ROWS))))[:n]
         if control:
-            ctrl = jnp.argmax(_logits(view, tokens, rows, items, True), -1)
+            ctrl = jnp.argmax(_logits(view, tokens, rows, layer, items, True),
+                              -1)
             ctrl_gap = np.asarray(_gaps(ref, ctrl.astype(jnp.int32)))[:n]
     return gap, ctrl_gap
 

@@ -7,9 +7,10 @@
 One run as ``run.py`` makes it (``cell.run``), with what the benchmark's
 own files do not read yet:
 
-- a listener keeps each step's ``StepTimes`` (the wall seconds of the
-  program's ``echo.*`` spans, its launches and syncs) and the front-door
-  stamps of the online requests the step admits for the first time;
+- each step's row (``cell.IterRow``) carries its ``StepTimes`` (the wall
+  seconds of the program's ``echo.*`` spans, its launches and syncs) and
+  the front-door stamps of the online requests the step admits for the
+  first time, and the readers below read them;
 - in a traced run the device trace keeps the program's ``echo.*`` host
   spans beside the benchmark's ``bench.*`` ones, so each idle gap goes to
   the innermost span of either. The ``bench.*`` spans, and so the ten
@@ -31,7 +32,6 @@ import sys
 import tempfile
 import time
 from contextlib import contextmanager
-from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
@@ -43,27 +43,8 @@ PHASES = ("schedule", "swap", "prep", "launch", "wait", "fetch", "argmax",
           "commit", "emit", "threshold", "observe")
 
 
-@dataclass
-class StepRow:
-    t_end: float                  # benchmark clock at step end
-    times: object                 # the step's ``StepTimes``
-    online_admits: List[Tuple[float, float, float]] = field(
-        default_factory=list)     # (submit, intake, admit) perf_counter
-
-
-def step_row(t_end: float, detail) -> StepRow:
-    """The row of one step: its times, and the stamps of the online
-    requests that this step admits for the first time."""
-    t0 = detail.times.t_start
-    admits = [(r.wall_submit, r.wall_intake, r.wall_admit)
-              for r in detail.admitted
-              if r.is_online and r.wall_admit == t0
-              and r.wall_submit is not None]
-    return StepRow(t_end, detail.times, admits)
-
-
 # ------------------------------------------------------------- readers
-def queue_wait(rows: List[StepRow]) -> Optional[dict]:
+def queue_wait(rows: List["cell.IterRow"]) -> Optional[dict]:
     """``frontdoor.queue_wait_ms``: median over the online requests first
     admitted in ``rows`` of the wall time from front-door submit to the
     start of the admitting step, with its p95, the sample count and the
@@ -81,7 +62,7 @@ def queue_wait(rows: List[StepRow]) -> Optional[dict]:
             "sched_median_ms": 1e3 * statistics.median(w[2] for w in waits)}
 
 
-def host_ms_per_step(rows: List[StepRow]) -> Optional[float]:
+def host_ms_per_step(rows: List["cell.IterRow"]) -> Optional[float]:
     """``engine.host_ms_per_step``: mean over the steps of the step's wall
     time less the time it waited for the device."""
     if not rows:
@@ -89,7 +70,7 @@ def host_ms_per_step(rows: List[StepRow]) -> Optional[float]:
     return 1e3 * sum(r.times.host for r in rows) / len(rows)
 
 
-def syncs_per_step(rows: List[StepRow]) -> Optional[float]:
+def syncs_per_step(rows: List["cell.IterRow"]) -> Optional[float]:
     """``runner.syncs_per_step``: mean blocking device-to-host fetches per
     step."""
     if not rows:
@@ -97,7 +78,7 @@ def syncs_per_step(rows: List[StepRow]) -> Optional[float]:
     return sum(r.times.n_syncs for r in rows) / len(rows)
 
 
-def sample_ms_per_step(rows: List[StepRow]) -> Optional[float]:
+def sample_ms_per_step(rows: List["cell.IterRow"]) -> Optional[float]:
     """``runner.sample_ms_per_step``: mean per step of the logits copy to
     the host and the host argmax."""
     if not rows:
@@ -105,7 +86,7 @@ def sample_ms_per_step(rows: List[StepRow]) -> Optional[float]:
     return 1e3 * sum(r.times.fetch + r.times.argmax for r in rows) / len(rows)
 
 
-def phase_ms(rows: List[StepRow]) -> Dict[str, float]:
+def phase_ms(rows: List["cell.IterRow"]) -> Dict[str, float]:
     """Mean ms per step of each phase, and of the step's wall time not
     under any phase (``other``)."""
     n = max(len(rows), 1)
@@ -147,9 +128,9 @@ def program_share(idle: Dict[str, float]) -> Optional[float]:
 # ------------------------------------------------------------- the run
 @contextmanager
 def program_spans(cell, devtrace, top: int = 40):
-    """For the length of one ``cell.run``: the counters listener also keeps
-    ``StepRow``s, the step hook is kept, and the device trace keeps the
-    program's spans (and ``top`` entries of each breakdown)."""
+    """For the length of one ``cell.run``: the counters listener and the
+    step hook are kept, and the device trace keeps the program's spans
+    (and ``top`` entries of each breakdown)."""
     made = {}
     saved = (cell.Counters, cell.StepHook, devtrace.HOST_PREFIX,
              devtrace.reduce)
@@ -158,12 +139,7 @@ def program_spans(cell, devtrace, top: int = 40):
     class Counters(saved[0]):
         def __init__(self, clock):
             super().__init__(clock)
-            self.steps: List[StepRow] = []
             made["counters"] = self
-
-        def on_iteration(self, rec, detail):
-            super().on_iteration(rec, detail)
-            self.steps.append(step_row(self.rows[-1].t_end, detail))
 
     class StepHook(saved[1]):
         def __init__(self, *a, **k):
@@ -217,7 +193,7 @@ def measure(workload: str, seed: int, seconds: float, trace: bool,
         result = cell.run(workload, seed, seconds, trace, **run_kw)
         counters, hook = made["counters"], made["hook"]
     w0, w1 = cell.WARMUP_S, cell.WARMUP_S + seconds   # traffic.py's window
-    rows = [r for r in counters.steps if w0 <= r.t_end < w1]
+    rows = [r for r in counters.rows if w0 <= r.t_end < w1]
     out = {"steps": len(rows),
            "frontdoor.queue_wait_ms": queue_wait(rows),
            "engine.host_ms_per_step": host_ms_per_step(rows),
@@ -226,7 +202,7 @@ def measure(workload: str, seed: int, seconds: float, trace: bool,
            "phase_ms": phase_ms(rows)}
     if trace and "breakdown" in result:
         a, b = hook.traced_rows
-        traced = counters.steps[a:b]
+        traced = counters.rows[a:b]
         window = result["device"]["window_s"]
         idle = idle_by_span(result["breakdown"]["idle_gaps"])
         out.update({

@@ -27,9 +27,12 @@ def test_every_metric_has_a_reader():
 
 def test_metrics_of_a_cell():
     e2e = common.metrics_of("qwen3-4b.docqa-chat", "end_to_end")
-    assert "setup_s" in e2e and "online_itl_p95_ms" in e2e
+    assert "setup_s" in e2e and "offline_out_tok_s" in e2e
+    assert "online_itl_p95_ms" not in e2e
+    assert "online_itl_p95_ms" in common.metrics_of("qwen3-4b.gen-chat",
+                                                    "end_to_end")
     layer = common.metrics_of("qwen3-4b.docqa-chat", "per_layer")
-    assert "device.idle_pct" in layer
+    assert "device.idle_pct" in layer and "frontdoor.itl_p95_ms" in layer
     assert common.metrics_of("no-such.cell", "per_layer") == {
         m["name"]: m for m in common.benchmark_spec()["per_layer"]
         if "workloads" not in m}
@@ -54,3 +57,17 @@ def test_no_reader_returns_zero_without_data():
                            common.peaks("TPU v5 lite"), [])
     for m in common.benchmark_spec()["per_layer"]:
         assert common.metric_reader(m["name"])(ctx) is None, m["name"]
+
+
+def test_every_layer_metric_moves_what_its_cells_report():
+    """A per-layer metric's ``moves`` is an end-to-end metric that each
+    cell it lists reports; every cell reports ``setup_s``, another
+    end-to-end metric and a per-layer one."""
+    spec = common.benchmark_spec()
+    for cell in spec["workloads"]:
+        e2e = common.metrics_of(cell["name"], "end_to_end")
+        assert "setup_s" in e2e and len(e2e) >= 2, cell["name"]
+        layer = common.metrics_of(cell["name"], "per_layer")
+        assert layer, cell["name"]
+        for name, m in layer.items():
+            assert m["moves"] in e2e, (cell["name"], name)

@@ -88,6 +88,7 @@ def test_counters_note_only_an_offline_first_admission():
     """A re-admitted request, which re-finds its own blocks, and online
     requests add nothing to the prefix hits."""
     from types import SimpleNamespace as NS
+    from repro.core.engine import StepTimes
     from repro.core.request import Request, TaskType
     off = Request(tuple(range(300)), 4, TaskType.OFFLINE)
     on = Request(tuple(range(50)), 4, TaskType.ONLINE)
@@ -96,10 +97,46 @@ def test_counters_note_only_an_offline_first_admission():
     def step(spans):
         counters.on_iteration(None, NS(
             prefill_spans=spans, schedule_wall=0.0, compute_time=0.01,
-            predicted_time=0.01, decodes=[]))
+            predicted_time=0.01, decodes=[], admitted=[],
+            times=StepTimes()))
 
     step([(off, 256, 300), (on, 0, 50)])
     off.recomputed_tokens = 44
     step([(off, 256, 300)])
     assert [r.offline_first for r in counters.rows] == [[(256, 300)], []]
     assert [r.recomputed for r in counters.rows] == [0, 44]
+
+
+def test_rows_carry_the_step_times_and_first_admissions():
+    """``IterRow.times`` is the step's own ``StepTimes``; the front-door
+    stamps are those of the online requests the step admits first."""
+    from types import SimpleNamespace as NS
+    from repro.core.engine import StepTimes
+    from repro.core.request import Request, TaskType
+    on = [Request(tuple(range(50)), 4, TaskType.ONLINE) for _ in range(3)]
+    off = Request(tuple(range(80)), 4, TaskType.OFFLINE)
+    for i, r in enumerate(on + [off]):
+        r.wall_submit, r.wall_intake = 0.5 + i, 0.7 + i
+        r.wall_admit = 2.0
+    on[2].wall_admit = 1.0                 # re-admitted after a preemption
+    times = StepTimes(t_start=2.0, t_end=2.1, wait=0.05, n_syncs=1)
+    counters = cell.Counters(cell.Clock())
+    counters.on_iteration(None, NS(
+        prefill_spans=[], schedule_wall=0.0, compute_time=0.01,
+        predicted_time=0.01, decodes=[], admitted=on + [off], times=times))
+    (row,) = counters.rows
+    assert row.times is times
+    assert row.online_admits == [(0.5, 0.7, 2.0), (1.5, 1.7, 2.0)]
+
+
+def test_frontdoor_itl_reads_the_runs_own_p95():
+    """The per-layer ITL p95 is the end-to-end reading of the same run,
+    and nothing where no online gap landed in the window."""
+    reader = common.metric_reader("frontdoor.itl_p95_ms")
+    clients = [_client(11.0 + i, [11.1 + i + 0.05 * k for k in range(20)],
+                       20) for i in range(5)]
+    m, _ = cell.end_to_end(clients, WINDOW, SLO)
+    ctx = cell.ReadContext({}, {}, [], e2e=m)
+    assert reader(ctx) == m["online_itl_p95_ms"] == pytest.approx(50.0)
+    empty, _ = cell.end_to_end([], WINDOW, SLO)
+    assert reader(cell.ReadContext({}, {}, [], e2e=empty)) is None

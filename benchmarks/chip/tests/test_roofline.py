@@ -11,7 +11,8 @@ PEAK = common.peaks("TPU v5 lite")
 def test_layer_weights():
     # q 2560x4096, k and v 2560x1024 each, o 4096x2560, MLP 3 x 2560x9728
     hand = 2560 * 4096 + 2 * 2560 * 1024 + 4096 * 2560 + 3 * 2560 * 9728
-    assert roofline.layer_matmul_params(Q) == hand == 100_925_440
+    assert common.family(Q).matmul_params_per_token(Q) == hand \
+        == 100_925_440
 
 
 def test_decode_attention():

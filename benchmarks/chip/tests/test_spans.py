@@ -78,8 +78,8 @@ def _rows(n):
     return [row] * n
 
 
-def test_the_ten_readings_are_unchanged_by_program_spans():
-    sample = common.load_json(common.HERE / "tests" / "trace_sample.json")
+def test_the_ten_readings_are_unchanged_by_program_spans(recorded_trace):
+    sample = recorded_trace
     dims = common.load_config("qwen3-4b")
     peak = common.peaks("TPU v5 lite")
     values = []
@@ -98,7 +98,8 @@ def _step(host_ms, wait_ms, syncs, fetch_ms, argmax_ms, admits=()):
     t = StepTimes(t_start=1.0, t_end=1.0 + (host_ms + wait_ms) / 1e3,
                   wait=wait_ms / 1e3, fetch=fetch_ms / 1e3,
                   argmax=argmax_ms / 1e3, n_syncs=syncs, n_launches=syncs)
-    return spans.StepRow(0.0, t, list(admits))
+    return cell.IterRow(0.0, t.schedule, 0.0, 0.0, [], [], 0, [], t,
+                        list(admits))
 
 
 def test_the_four_readings_on_hand_made_steps():

@@ -10,8 +10,11 @@ leaf from a key folded from the leaf's path:
 - norm scales: uniform on [0.8, 1.2], so that a norm whose scale is
   dropped shows in the comparison.
 
-A leaf whose name is not listed below is an error: a new layout needs its
-rule here before it can be measured.
+The rules by leaf name (which axes a matrix contracts over, which leaves
+are norm scales and which embeddings) are the family's
+(``families/<family>.py``: ``FAN_IN_AXES``, ``NORMS``, ``EMBEDS``). A leaf
+whose name is in none of them is an error: a new layout needs its rule
+there before it can be measured.
 """
 from __future__ import annotations
 
@@ -20,12 +23,6 @@ import zlib
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-# axes that a matrix contracts over, after the stacked layer axis
-FAN_IN_AXES = {"wq": (0,), "wk": (0,), "wv": (0,), "wo": (0, 1),
-               "w1": (0,), "w3": (0,), "w2": (0,)}
-NORMS = {"ln1", "ln2", "final_ln", "q_norm", "k_norm"}
-EMBEDS = {"embed", "unembed"}
 
 
 def seed_key(seed: int):
@@ -39,24 +36,26 @@ def _leaf_name(path) -> str:
     return str(path[-1].key)
 
 
-def _draw(key, name: str, shape, dtype, stacked: bool):
-    if name in NORMS:
+def _draw(key, name: str, shape, dtype, stacked: bool, rules):
+    if name in rules.NORMS:
         return jax.random.uniform(key, shape, jnp.float32, 0.8, 1.2
                                   ).astype(dtype)
-    if name in EMBEDS:
+    if name in rules.EMBEDS:
         return (jax.random.normal(key, shape, jnp.float32) * 0.02
                 ).astype(dtype)
-    if name in FAN_IN_AXES:
+    if name in rules.FAN_IN_AXES:
         dims = shape[1:] if stacked else shape
-        fan_in = int(np.prod([dims[a] for a in FAN_IN_AXES[name]]))
+        fan_in = int(np.prod([dims[a] for a in rules.FAN_IN_AXES[name]]))
         return (jax.random.normal(key, shape, jnp.float32)
                 / np.sqrt(fan_in)).astype(dtype)
-    raise KeyError(f"no weight rule for parameter {name!r}")
+    raise KeyError(f"no weight rule for parameter {name!r} in "
+                   f"{rules.__file__}")
 
 
-def make_weights(specs, seed: int):
+def make_weights(specs, seed: int, rules):
     """Weights shaped as ``specs`` (the program's parameter shapes, e.g.
-    ``jax.eval_shape(model.init, key)``), on the default device."""
+    ``jax.eval_shape(model.init, key)``), on the default device, drawn by
+    the rules of the family module ``rules``."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(specs)
 
     def gen(key):
@@ -65,7 +64,7 @@ def make_weights(specs, seed: int):
             where = jax.tree_util.keystr(path)
             k = jax.random.fold_in(key, zlib.crc32(where.encode()) & 0x7FFFFFFF)
             out.append(_draw(k, _leaf_name(path), s.shape, s.dtype,
-                             "layers" in where))
+                             "layers" in where, rules))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     return jax.jit(gen)(seed_key(seed))
