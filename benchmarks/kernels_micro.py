@@ -46,6 +46,12 @@ def _time(fn, reps=10):
     return (time.perf_counter() - t0) / reps * 1e6
 
 
+def _one_layer(pool):
+    """A (P, bs, Hkv, hd) pool as the one layer of a lane-layout stack, the
+    form ``paged_decode_attention`` reads."""
+    return pool.reshape(1, *pool.shape[:2], -1)
+
+
 def _matches(got, want):
     return bool(np.allclose(np.asarray(got, np.float32),
                             np.asarray(want, np.float32),
@@ -75,7 +81,7 @@ def rows(strict: bool = True):
     ok = _matches(paged_attention(q, kp, vp, bt, cl, interpret=True), want)
     out.append(("kernel.paged_attention", us, f"pallas_matches={ok}"))
     ok = _matches(
-        paged_decode_attention(q, kp, vp, bt, cl,
+        paged_decode_attention(q, _one_layer(kp), _one_layer(vp), bt, cl, 0,
                                pages_per_block=tune.pages_per_block,
                                interpret=True), want)
     out.append(("kernel.paged_decode_attention", us, f"pallas_matches={ok}"))
@@ -90,8 +96,8 @@ def rows(strict: bool = True):
     want2 = jit_ref(q2, kp2, vp2, bt2, cl2)
     us = _time(lambda: jit_ref(q2, kp2, vp2, bt2, cl2))
     ok = _matches(
-        paged_decode_attention(q2, kp2, vp2, bt2, cl2,
-                               pages_per_block=tune.pages_per_block,
+        paged_decode_attention(q2, _one_layer(kp2), _one_layer(vp2), bt2,
+                               cl2, 0, pages_per_block=tune.pages_per_block,
                                interpret=True), want2)
     out.append(("kernel.paged_decode_attention_long", us,
                 f"pallas_matches={ok}"))

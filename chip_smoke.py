@@ -115,7 +115,11 @@ def kernel_check(cfg, seed: int) -> None:
     vp = jax.random.normal(ks[2], (pages, PAGE_SIZE, hkv, hd), bf)
     bt = jax.random.randint(ks[3], (b, MAX_PAGES_PER_SEQ), 0, pages)
     ctx = jnp.asarray([1, 15, 16, 17, 300, 511, 777, 1024], jnp.int32)
-    got = ops.paged_attention(q, kp, vp, bt, ctx,
+    # the serving pool's form: every layer in the lane layout; read layer 1
+    stack = [jnp.stack([jnp.zeros_like(x), x]).reshape(2, pages, PAGE_SIZE,
+                                                       hkv * hd)
+             for x in (kp, vp)]
+    got = ops.paged_attention(q, *stack, bt, ctx, 1,
                               impl="paged_decode_attention")
     err = max_err(got, f32_ref(ref.ref_paged_attention, q, kp, vp, bt, ctx))
     log(f"kernel paged decode         B={b} Hq={hq} Hkv={hkv} hd={hd} "

@@ -95,21 +95,26 @@ def _resolve(impl: str) -> str:
     return impl
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
+def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, layer,
                     impl="pallas", preset=None, pages_per_block=None):
-    """Decode attention dispatch. ``impl`` in {auto, ref, pallas,
-    paged_decode_attention}; ``preset`` picks the tuning table for the
-    pages per block, overridable via ``pages_per_block``."""
+    """Decode attention dispatch over the serving pool: k/v_pages
+    (L,P,bs,Hkv*hd), every layer in the lane layout; ``layer`` the one to
+    read. ``impl`` in {auto, ref, pallas, paged_decode_attention};
+    ``preset`` picks the tuning table for the pages per block, overridable
+    via ``pages_per_block``."""
     impl = _resolve(impl)
-    if impl == "ref":
-        return ref_mod.ref_paged_attention(q, k_pages, v_pages, block_tables,
-                                           ctx_lens)
     if impl == "paged_decode_attention":
         tune = kernel_tuning(preset).override(pages_per_block=pages_per_block)
         return _pallas_decode(q, k_pages, v_pages, block_tables, ctx_lens,
-                              pages_per_block=tune.pages_per_block,
+                              layer, pages_per_block=tune.pages_per_block,
                               interpret=_interpret())
-    return _pallas_paged(q, k_pages, v_pages, block_tables, ctx_lens,
+    # the oracle and the legacy kernel take one layer's (P,bs,Hkv,hd) pool
+    hd = q.shape[-1]
+    kp, vp = (x[layer].reshape(*x.shape[1:-1], -1, hd)
+              for x in (k_pages, v_pages))
+    if impl == "ref":
+        return ref_mod.ref_paged_attention(q, kp, vp, block_tables, ctx_lens)
+    return _pallas_paged(q, kp, vp, block_tables, ctx_lens,
                          interpret=_interpret())
 
 

@@ -17,11 +17,15 @@ Two schedules over the page dimension:
   current one is computed. A running softmax per KV head is kept in f32
   VMEM across the row's blocks and normalised after its last block.
 
-Both read the pool through a lane view: the wrapper reshapes the
-``(P, bs, Hkv, hd)`` pool to ``(P, bs, Hkv*hd)`` (free for a contiguous
-array) and a head's keys are the lanes ``[h*hd, (h+1)*hd)``. Mosaic tiles
-the last two dims by (8, 128) unless they span the whole array dim, so a
-head's lanes only tile when ``hd % 128 == 0`` (or there is one KV head).
+Both read pages in the lane layout ``(.., bs, Hkv*hd)``, a head's keys the
+lanes ``[h*hd, (h+1)*hd)``. Mosaic tiles the last two dims by (8, 128)
+unless they span the whole array dim, so a head's lanes only tile when
+``hd % 128 == 0`` (or there is one KV head). The TPU stores an array
+tiled over its last two dims, so ``(bs, Hkv, hd)`` -> ``(bs, Hkv*hd)`` is a
+relayout, a copy of every byte, not a free reshape. ``paged_decode_attention``
+therefore takes the serving pool as it is kept, already in the lane layout
+and stacked over layers, ``(L, P, bs, Hkv*hd)``, with the layer to read as a
+scalar; the legacy kernel lane-views a ``(P, bs, Hkv, hd)`` pool itself.
 """
 from __future__ import annotations
 
@@ -36,16 +40,24 @@ NEG_INF = -1e30
 LANES = 128
 
 
-def lane_view(x, interpret: bool):
-    """(..., H, hd) -> (..., H*hd), the layout whose ``(.., hd)`` head
-    blocks the TPU compiler accepts. Raises on a compiled (non-interpret)
-    call whose head_dim the view cannot tile — never a silent fallback."""
-    *lead, h, hd = x.shape
+def _check_lanes(shape, h: int, hd: int, interpret: bool) -> None:
+    """Raise on a compiled (non-interpret) call whose ``(.., hd)`` head
+    blocks of an ``h * hd``-lane row the TPU cannot tile — never a silent
+    fallback. ``shape`` names the array in the message."""
     if not interpret and h > 1 and hd % LANES:
         raise ValueError(
-            f"TPU attention kernel cannot tile array of shape {x.shape}: "
+            f"TPU attention kernel cannot tile array of shape {tuple(shape)}: "
             f"a ({hd},)-lane head block of a {h * hd}-lane row needs "
             f"head_dim % {LANES} == 0 (or one head)")
+
+
+def lane_view(x, interpret: bool):
+    """(..., H, hd) -> (..., H*hd), the layout whose ``(.., hd)`` head
+    blocks the TPU compiler accepts. On the TPU this is a relayout copy of
+    ``x`` (see the module docstring), so it suits operands made per call,
+    not the page pool."""
+    *lead, h, hd = x.shape
+    _check_lanes(x.shape, h, hd, interpret)
     return x.reshape(*lead, h * hd)
 
 
@@ -130,6 +142,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
 
 
 def _decode_kernel(block_tables_ref, ctx_lens_ref,   # scalar prefetch (SMEM)
+                   layer_ref,
                    q_ref, k_hbm, v_hbm,               # q block; pools in HBM
                    o_ref,
                    k_buf, v_buf, sems, slot_ref,      # page buffers, DMA state
@@ -143,12 +156,14 @@ def _decode_kernel(block_tables_ref, ctx_lens_ref,   # scalar prefetch (SMEM)
     current block is computed, the next row's first block during this
     row's last, so a row's first copy is hidden behind the row before it.
     ``slot_ref`` carries the buffer slot of the next row's first block
-    across grid steps (the grid runs in order on one TensorCore)."""
+    across grid steps (the grid runs in order on one TensorCore). The
+    pools hold every layer; pages are copied from layer ``layer_ref[0]``."""
     b = pl.program_id(0)
     nrows = pl.num_programs(0)
     ppb, bs = pages_per_block, page_size
     blk_tok = ppb * bs
     hkv, g, hd = q_ref.shape[1:]
+    layer = layer_ref[0]
 
     def live_pages(r):
         return jnp.minimum(pl.cdiv(ctx_lens_ref[r], bs), nblk)
@@ -160,7 +175,7 @@ def _decode_kernel(block_tables_ref, ctx_lens_ref,   # scalar prefetch (SMEM)
         def page(i, carry):
             pid = block_tables_ref[r * nblk + j * ppb + i]
             for n, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
-                c = pltpu.make_async_copy(src.at[pid], dst.at[slot, i],
+                c = pltpu.make_async_copy(src.at[layer, pid], dst.at[slot, i],
                                           sems.at[n, slot])
                 if start:
                     c.start()
@@ -245,27 +260,31 @@ def _pv(p, v):
 
 @functools.partial(jax.jit,
                    static_argnames=("pages_per_block", "interpret"))
-def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
+def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, layer,
                            *, pages_per_block: int = 4,
                            interpret: bool = False):
-    """Decode attention over a row's live pages. Same contract as
-    ``paged_attention``: q (B,Hq,hd); k/v_pages (P,bs,Hkv,hd);
-    block_tables (B,nblk) int32; ctx_lens (B,) int32 -> (B,Hq,hd).
+    """Decode attention over a row's live pages of one layer. q (B,Hq,hd);
+    k/v_pages (L,P,bs,Hkv*hd), the pool of every layer in the lane layout;
+    block_tables (B,nblk) int32; ctx_lens (B,) int32; layer, the layer to
+    read (int32 scalar) -> (B,Hq,hd).
 
     Grid ``(B,)``; row ``b`` copies and computes ``cdiv(ctx, ppb * bs)``
     blocks of ``ppb = pages_per_block`` pages and no page past its last
     live one; a row with ``ctx == 0`` copies nothing and writes zeros.
+    The pools stay in HBM and are only read: page ``pid`` of the layer is
+    one DMA of ``k_pages[layer, pid]``.
     """
     b, hq, hd = q.shape
-    _, page_size, hkv, _ = k_pages.shape
+    _, _, page_size, lanes = k_pages.shape
+    hkv = lanes // hd
+    _check_lanes(k_pages.shape, hkv, hd, interpret)
     g = hq // hkv
     nblk = block_tables.shape[1]
     ppb = max(1, min(pages_per_block, nblk))
-    lanes = hkv * hd
     qg = q.reshape(b, hkv, g, hd)
-    row = pl.BlockSpec((1, hkv, g, hd), lambda bb, bt, cl: (bb, 0, 0, 0))
+    row = pl.BlockSpec((1, hkv, g, hd), lambda bb, bt, cl, ly: (bb, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[row,
                   pl.BlockSpec(memory_space=pl.ANY),
@@ -291,6 +310,6 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_tables.reshape(-1), ctx_lens, qg,
-      lane_view(k_pages, interpret), lane_view(v_pages, interpret))
+    )(block_tables.reshape(-1), ctx_lens,
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pages, v_pages)
     return out.reshape(b, hq, hd)

@@ -1,10 +1,25 @@
 """Paged-KV execution path for the serving engine (attention families).
 
-KV lives in a global page pool per layer; requests reference pages through
-block tables (the BlockManager owns the indirection). On TPU the attention
+KV lives in a global page pool; requests reference pages through block
+tables (the BlockManager owns the indirection). On TPU the attention
 inner loops are the Pallas kernels in repro.kernels; on CPU the jnp ref
 oracles execute the same layout. Prefill is chunked (Sarathi-style) and
 decode is batched — the two batch shapes Echo's scheduler composes.
+
+The pool is updated in place, never copied whole:
+
+* Each segment keeps one K and one V array per unit kind, stacked over its
+  layers in the decode kernel's lane layout, ``(n_layers, P, bs, Hkv*hd)``.
+  On the TPU a ``(bs, Hkv, hd)`` -> ``(bs, Hkv*hd)`` reshape is a relayout
+  copy, so no call reshapes the pool; the host KV tier's payloads stay
+  ``(n_layers, bs, Hkv, hd)``, reshaped one page at a time.
+* The prefill and decode programs donate the pool: ``(params, ..., pages)
+  -> (out, pages)`` with the output pool aliasing the input.
+* The layer scan carries the stacked pool (``(h, pages)``) beside the
+  layer index instead of scanning it as xs/ys, which would slice each
+  layer out and write it back. A layer scatters its new K/V rows into the
+  carried stack at ``[layer, page, slot]``; decode attention reads the
+  stack at that layer and prefill gathers ``stack[layer, pages]``.
 """
 from __future__ import annotations
 
@@ -23,71 +38,74 @@ from repro.models.moe import moe_apply
 from repro.obs.spans import span
 
 
-def _write_pages(pages, flat_idx, new_k):
-    """pages (P,bs,H,hd); flat_idx (N,) into P*bs; entries >= P*bs are
-    dropped. NOTE: the drop sentinel must be positive-OOB — JAX scatter
-    *wraps* negative indices instead of dropping them."""
-    p, bs, h, hd = pages.shape
-    flat = pages.reshape(p * bs, h, hd)
-    flat = flat.at[flat_idx].set(new_k, mode="drop")
-    return flat.reshape(p, bs, h, hd)
+def _write_pages(stack, layer, flat_idx, rows):
+    """stack (L,P,bs,Hkv*hd); rows (N,Hkv,hd) land at ``[layer, flat_idx]``
+    with flat_idx (N,) into P*bs; entries >= P*bs are dropped. NOTE: the
+    drop sentinel must be positive-OOB — JAX scatter *wraps* negative
+    indices instead of dropping them."""
+    _, _, bs, lanes = stack.shape
+    rows = rows.reshape(rows.shape[0], lanes).astype(stack.dtype)
+    return stack.at[layer, flat_idx // bs, flat_idx % bs].set(rows,
+                                                              mode="drop")
 
 
-def _gather_pages(pages, block_table):
-    """pages (P,bs,H,hd); block_table (nblk,) -> (nblk*bs, H, hd)."""
-    p, bs, h, hd = pages.shape
-    t = block_table.shape[0] * bs
-    tok = jnp.arange(t)
-    idx = block_table[tok // bs] * bs + tok % bs
-    return pages.reshape(p * bs, h, hd)[idx]
+def _gather_pages(stack, layer, block_table, hd):
+    """stack (L,P,bs,Hkv*hd); block_table (nblk,) -> the layer's pages
+    (nblk*bs, Hkv, hd)."""
+    _, _, bs, lanes = stack.shape
+    pages = stack[layer, block_table]                   # (nblk, bs, lanes)
+    return pages.reshape(block_table.shape[0] * bs, lanes // hd, hd)
 
 
-def _attn_prefill_paged(p, cfg, x, cos, sin, k_pages, v_pages, block_table,
-                        ctx_len, chunk_len, impl="auto", preset=None):
-    """x (1,Sc,d). Writes chunk KV into pages, attends vs prefix+chunk."""
+def _attn_prefill_paged(p, cfg, x, cos, sin, k_pages, v_pages, layer,
+                        block_table, ctx_len, chunk_len, impl="auto",
+                        preset=None):
+    """x (1,Sc,d). Writes chunk KV into the layer's pages, attends vs
+    prefix+chunk."""
     from repro.models.attention import _qkv
     sc = x.shape[1]
     q, k, v = _qkv(p, cfg, x, cos, sin)              # (1,Sc,H*,hd)
     ar = jnp.arange(sc)
     pos = ctx_len + ar
-    bs = k_pages.shape[1]
-    oob = k_pages.shape[0] * bs                  # positive-OOB drop sentinel
+    bs = k_pages.shape[2]
+    oob = k_pages.shape[1] * bs                  # positive-OOB drop sentinel
     idx = block_table[pos // bs] * bs + pos % bs
     idx = jnp.where(ar < chunk_len, idx, oob)
-    k_pages = _write_pages(k_pages, idx, k[0])
-    v_pages = _write_pages(v_pages, idx, v[0])
-    kk = _gather_pages(k_pages, block_table)
-    vv = _gather_pages(v_pages, block_table)
+    k_pages = _write_pages(k_pages, layer, idx, k[0])
+    v_pages = _write_pages(v_pages, layer, idx, v[0])
+    hd = q.shape[-1]
+    kk = _gather_pages(k_pages, layer, block_table, hd)
+    vv = _gather_pages(v_pages, layer, block_table, hd)
     out = kops.chunked_prefill_attention(q[0], kk, vv, ctx_len, impl=impl,
                                          preset=preset)
     out = jnp.einsum("shk,hkd->sd", out, p["wo"])[None]
     return out, k_pages, v_pages
 
 
-def _attn_decode_paged(p, cfg, x, cos, sin, k_pages, v_pages, block_tables,
-                       pos, impl="auto", preset=None):
+def _attn_decode_paged(p, cfg, x, cos, sin, k_pages, v_pages, layer,
+                       block_tables, pos, impl="auto", preset=None):
     """x (B,1,d); block_tables (B,nblk); pos (B,). ctx = pos + 1."""
     from repro.models.attention import _qkv
     b = x.shape[0]
     q, k, v = _qkv(p, cfg, x, cos, sin)
-    bs = k_pages.shape[1]
-    oob = k_pages.shape[0] * bs                  # positive-OOB drop sentinel
+    bs = k_pages.shape[2]
+    oob = k_pages.shape[1] * bs                  # positive-OOB drop sentinel
     bidx = jnp.arange(b)
     safe_pos = jnp.maximum(pos, 0)
     flat_idx = block_tables[bidx, safe_pos // bs] * bs + safe_pos % bs
     flat_idx = jnp.where(pos >= 0, flat_idx, oob)     # padded rows: drop
-    k_pages = _write_pages(k_pages, flat_idx, k[:, 0])
-    v_pages = _write_pages(v_pages, flat_idx, v[:, 0])
+    k_pages = _write_pages(k_pages, layer, flat_idx, k[:, 0])
+    v_pages = _write_pages(v_pages, layer, flat_idx, v[:, 0])
     out = kops.paged_attention(q[:, 0], k_pages, v_pages, block_tables,
-                               pos + 1, impl=impl, preset=preset)
+                               pos + 1, layer, impl=impl, preset=preset)
     out = jnp.einsum("bhk,hkd->bd", out, p["wo"])[:, None]
     return out, k_pages, v_pages
 
 
-def _block_paged(kind, p, cfg, x, rope, pages, attn_fn):
+def _block_paged(kind, p, cfg, x, rope, pages, layer, attn_fn):
     cos, sin = rope
     h, kp, vp = attn_fn(p["attn"], cfg, rms_norm(x, p["ln1"], cfg.norm_eps),
-                        cos, sin, pages["k"], pages["v"])
+                        cos, sin, pages["k"], pages["v"], layer)
     x = x + h
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     x = x + (swiglu(p["mlp"], h2) if kind == "attn"
@@ -122,16 +140,19 @@ class PagedRunner:
         self.tuning = kops.kernel_tuning(kernel_profile)
         self.io = io_spec_for_model(model)   # paged: per-token KV payload
         dt = model.dtype
-        shp = (num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
+        # the decode kernel's lane layout, stacked over a segment's layers
+        shp = (num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
         self.pages = []
         for stype, unit, n in tfm.segments(cfg):
             seg = tuple({"k": jnp.zeros((n,) + shp, dt),
                          "v": jnp.zeros((n,) + shp, dt)} for _ in unit)
             self.pages.append(seg)
-        self._prefill_jit = jax.jit(self._prefill_impl)
-        self._decode_jit = jax.jit(self._decode_impl)
-        # donate the pool so XLA updates the page in place instead of
-        # copying the whole pool per restored block
+        # every program donates the pool, so XLA updates it in place: a
+        # model call writes its new rows, a restore its page, and none
+        # copies the pool
+        self._prefill_jit = jax.jit(self._prefill_impl,
+                                    donate_argnames="pages")
+        self._decode_jit = jax.jit(self._decode_impl, donate_argnames="pages")
         self._write_block_jit = jax.jit(self._write_block_impl,
                                         donate_argnums=0)
 
@@ -144,26 +165,29 @@ class PagedRunner:
                            cfg.mrope_sections)
 
     def _run_stack(self, params, h, rope, pages, attn_fn):
+        """Every layer over ``h``. A scanned segment carries its stacked
+        pool through the scan beside ``h`` and takes the layer index with
+        the layer's params, so each layer updates the stack in place."""
         cfg = self.model.cfg
         new_pages = []
         for (stype, unit, n), seg_p, seg_pg in zip(
                 tfm.segments(cfg), params["layers"], pages):
-            if stype == "scan":
-                def body(x, xs, unit=unit):
-                    p_slice, pg_slice = xs
-                    outs = []
-                    for kind, p_k, pg_k in zip(unit, p_slice, pg_slice):
-                        x, pg = _block_paged(kind, p_k, cfg, x, rope, pg_k, attn_fn)
-                        outs.append(pg)
-                    return x, tuple(outs)
-                h, seg_new = jax.lax.scan(body, h, (seg_p, seg_pg))
-            else:
+            def layer_step(x, seg_pg, p_slice, layer, unit=unit):
                 outs = []
-                for kind, p_k, pg_k in zip(unit, seg_p, seg_pg):
-                    h, pg = _block_paged(kind, p_k, cfg, h, rope, pg_k, attn_fn)
+                for kind, p_k, pg_k in zip(unit, p_slice, seg_pg):
+                    x, pg = _block_paged(kind, p_k, cfg, x, rope, pg_k,
+                                         layer, attn_fn)
                     outs.append(pg)
-                seg_new = tuple(outs)
-            new_pages.append(seg_new)
+                return x, tuple(outs)
+
+            if stype == "scan":
+                def body(carry, xs, layer_step=layer_step):
+                    return layer_step(*carry, *xs), None
+                (h, seg_pg), _ = jax.lax.scan(body, (h, seg_pg),
+                                              (seg_p, jnp.arange(n)))
+            else:
+                h, seg_pg = layer_step(h, seg_pg, seg_p, 0)
+            new_pages.append(seg_pg)
         return h, new_pages
 
     def _prefill_impl(self, params, tokens, ctx_len, chunk_len, block_table,
@@ -173,8 +197,8 @@ class PagedRunner:
         positions = (ctx_len + jnp.arange(sc))[None]                  # (1,Sc)
         rope = self._rope_for(positions)
         h = jnp.take(params["embed"], tokens[None], axis=0)
-        attn_fn = (lambda p, c, x, cos, sin, kp, vp: _attn_prefill_paged(
-            p, c, x, cos, sin, kp, vp, block_table, ctx_len, chunk_len,
+        attn_fn = (lambda p, c, x, cos, sin, kp, vp, layer: _attn_prefill_paged(
+            p, c, x, cos, sin, kp, vp, layer, block_table, ctx_len, chunk_len,
             impl=self.attn_impl, preset=self.kernel_profile))
         h, pages = self._run_stack(params, h, rope, pages, attn_fn)
         idx = jnp.maximum(chunk_len - 1, 0)
@@ -186,8 +210,8 @@ class PagedRunner:
         positions = jnp.maximum(pos, 0)[:, None]
         rope = self._rope_for(positions)
         h = jnp.take(params["embed"], tokens[:, None], axis=0)
-        attn_fn = (lambda p, c, x, cos, sin, kp, vp: _attn_decode_paged(
-            p, c, x, cos, sin, kp, vp, block_tables, pos,
+        attn_fn = (lambda p, c, x, cos, sin, kp, vp, layer: _attn_decode_paged(
+            p, c, x, cos, sin, kp, vp, layer, block_tables, pos,
             impl=self.attn_impl, preset=self.kernel_profile))
         h, pages = self._run_stack(params, h, rope, pages, attn_fn)
         logits = self._final_logits(params, h[:, 0])
@@ -206,14 +230,18 @@ class PagedRunner:
     # ------------------------------------------------------- host KV swap
     def snapshot_block(self, bid: int):
         """Phase 1 of a device->host block read: dispatch the per-layer page
-        slices and return the (possibly still in-flight) device arrays. Must
-        run on the thread that owns the pool — dispatch order sequences the
-        slice before any later compute or donated scatter overwrites the
-        page, so the snapshot always sees the pre-overwrite content."""
+        slices, each ``(n_layers, bs, Hkv, hd)``, and return the (possibly
+        still in-flight) device arrays. Must run on the thread that owns
+        the pool — dispatch order sequences the slice before any later
+        donating call overwrites the page, so the snapshot always sees the
+        pre-overwrite content."""
+        cfg = self.model.cfg
+        heads = (cfg.num_kv_heads, cfg.head_dim)
         out = []
         for seg in self.pages:
-            out.append(tuple({name: pg[name][:, bid] for name in ("k", "v")}
-                             for pg in seg))
+            out.append(tuple(
+                {name: pg[name][:, bid].reshape(-1, self.page_size, *heads)
+                 for name in ("k", "v")} for pg in seg))
         return out
 
     @staticmethod
@@ -250,7 +278,8 @@ class PagedRunner:
             for pg, blk in zip(seg, seg_payload):
                 new_seg.append({
                     name: pg[name].at[:, bid].set(
-                        blk[name].astype(pg[name].dtype))
+                        blk[name].reshape(pg[name].shape[0], self.page_size,
+                                          -1).astype(pg[name].dtype))
                     for name in ("k", "v")})
             new_pages.append(tuple(new_seg))
         return new_pages

@@ -101,6 +101,28 @@ def _paged_case(seed, b, hq, hkv, hd, bs, nblk, ctx_lens, dtype):
     return q, kp, vp, bt, cl
 
 
+LAYERS = 3      # layers of a stacked pool the decode kernel reads one of
+
+
+def _stacked(pool, layer, fill=None):
+    """A ``(P, bs, Hkv, hd)`` pool as layer ``layer`` of a
+    ``(LAYERS, P, bs, Hkv*hd)`` stack in the lane layout, the serving
+    pool's form; the other layers hold ``fill`` (other values if None)."""
+    lane = pool.reshape(*pool.shape[:2], -1)
+    shape = (LAYERS,) + lane.shape
+    other = (jax.random.normal(jax.random.PRNGKey(layer), shape, pool.dtype)
+             if fill is None else jnp.full(shape, fill, pool.dtype))
+    return other.at[layer].set(lane)
+
+
+def _decode(q, kp, vp, bt, cl, layer, pages_per_block, fill=None):
+    """The decode kernel on pools ``kp``/``vp`` placed at ``layer``."""
+    return paged_decode_attention(q, _stacked(kp, layer, fill),
+                                  _stacked(vp, layer, fill), bt, cl, layer,
+                                  pages_per_block=pages_per_block,
+                                  interpret=True)
+
+
 PAGED_DECODE_CASES = [
     # (b, hq, hkv, hd, bs, nblk, ctx_lens) — GQA group sizes 1 / 4 / 8,
     # ragged batches, and contexts shorter than a single page
@@ -111,18 +133,18 @@ PAGED_DECODE_CASES = [
 ]
 
 
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("case", PAGED_DECODE_CASES)
 @pytest.mark.parametrize("pages_per_block", [1, 2, 4])
-def test_paged_attention_splitk_sweep(dtype, case, pages_per_block):
+def test_paged_attention_splitk_sweep(dtype, case, pages_per_block, layer):
     """The decode kernel (``paged_decode_attention``, which replaced the
-    split-K schedule) against the oracle, 1-4 pages a block."""
+    split-K schedule) against the oracle, 1-4 pages a block, reading the
+    first or the last layer of a stacked pool."""
     b, hq, hkv, hd, bs, nblk, ctx_lens = case
     q, kp, vp, bt, cl = _paged_case(b * 7 + hq, b, hq, hkv, hd, bs, nblk,
                                     ctx_lens, dtype)
-    out = paged_decode_attention(q, kp, vp, bt, cl,
-                                 pages_per_block=pages_per_block,
-                                 interpret=True)
+    out = _decode(q, kp, vp, bt, cl, layer, pages_per_block)
     want = ref.ref_paged_attention(q, kp, vp, bt, cl)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -148,8 +170,7 @@ def test_paged_attention_splitk_oversized_split():
     """A block larger than the whole table degenerates to one block a row
     and must still match."""
     q, kp, vp, bt, cl = _paged_case(3, 2, 4, 2, 32, 8, 4, [32, 9], jnp.float32)
-    out = paged_decode_attention(q, kp, vp, bt, cl, pages_per_block=64,
-                                 interpret=True)
+    out = _decode(q, kp, vp, bt, cl, LAYERS - 1, pages_per_block=64)
     want = ref.ref_paged_attention(q, kp, vp, bt, cl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -166,11 +187,13 @@ def _poison_dead_pages(kp, vp, bt, cl, bs):
     return jnp.where(dead, jnp.nan, kp), jnp.where(dead, jnp.nan, vp)
 
 
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
 @pytest.mark.parametrize("pages_per_block", [1, 2, 4])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_never_reads_dead_pages(dtype, pages_per_block):
-    """K and V past each row's ctx hold NaN: the output stays finite and
-    matches the oracle on the clean pool."""
+def test_paged_decode_never_reads_dead_pages(dtype, pages_per_block, layer):
+    """K and V past each row's ctx hold NaN, and so does every other layer
+    of the stack: the output stays finite and matches the oracle on the
+    clean pool."""
     b, bs, nblk = 3, 8, 6
     # distinct pages a row, so no live page is also another row's dead one
     q, kp, vp, _, cl = _paged_case(5, b, 8, 2, 32, bs, nblk, [9, 30, 17],
@@ -178,9 +201,8 @@ def test_paged_decode_never_reads_dead_pages(dtype, pages_per_block):
     bt = jnp.arange(b * nblk, dtype=jnp.int32).reshape(b, nblk)[:, ::-1]
     want = ref.ref_paged_attention(q, kp, vp, bt, cl)
     kp_nan, vp_nan = _poison_dead_pages(kp, vp, bt, cl, bs)
-    out = paged_decode_attention(q, kp_nan, vp_nan, bt, cl,
-                                 pages_per_block=pages_per_block,
-                                 interpret=True)
+    out = _decode(q, kp_nan, vp_nan, bt, cl, layer, pages_per_block,
+                  fill=jnp.nan)
     assert np.isfinite(np.asarray(out, np.float32)).all()
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -195,9 +217,7 @@ def test_paged_decode_padded_row_writes_zeros():
     bt = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
     kp, vp = kp.at[bt[1]].set(jnp.nan), vp.at[bt[1]].set(jnp.nan)
     want = ref.ref_paged_attention(q, kp, vp, bt, cl)
-    out = np.asarray(paged_decode_attention(q, kp, vp, bt, cl,
-                                            pages_per_block=2,
-                                            interpret=True))
+    out = np.asarray(_decode(q, kp, vp, bt, cl, 1, pages_per_block=2))
     np.testing.assert_array_equal(out[1], 0.0)
     np.testing.assert_allclose(out[[0, 2]], np.asarray(want)[[0, 2]],
                                rtol=2e-4, atol=2e-4)
@@ -209,22 +229,21 @@ def test_paged_decode_block_edges(ctx_lens):
     and one short of it."""
     q, kp, vp, bt, cl = _paged_case(17, 2, 4, 2, 32, 8, 6, ctx_lens,
                                     jnp.float32)
-    out = paged_decode_attention(q, kp, vp, bt, cl, pages_per_block=2,
-                                 interpret=True)
+    out = _decode(q, kp, vp, bt, cl, 0, pages_per_block=2)
     want = ref.ref_paged_attention(q, kp, vp, bt, cl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
 @pytest.mark.parametrize("hkv", [8, 4], ids=["qwen3-4b", "yi-9b"])
-def test_paged_decode_served_gqa_shapes(hkv):
+def test_paged_decode_served_gqa_shapes(hkv, layer):
     """The served configurations' heads at head_dim 128 in bf16: Qwen3-4B
     (8 KV heads, group 4) and Yi-9B (4 KV heads, group 8), 32 query
-    heads, on a small pool of 16-token pages."""
+    heads, on a small stacked pool of 16-token pages."""
     q, kp, vp, bt, cl = _paged_case(19, 4, 32, hkv, 128, 16, 8,
                                     [100, 1, 64, 65], jnp.bfloat16)
-    out = paged_decode_attention(q, kp, vp, bt, cl, pages_per_block=4,
-                                 interpret=True)
+    out = _decode(q, kp, vp, bt, cl, layer, pages_per_block=4)
     want = ref.ref_paged_attention(q, kp, vp, bt, cl)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
@@ -255,17 +274,19 @@ def test_chunked_prefill_nondivisible_sweep(dtype, sc, t, hq, hkv, hd, ctx,
 
 
 def test_ops_dispatch_and_tuning():
-    """ops-layer routing: impl="ref" is the oracle,
-    impl="paged_decode_attention"/"pallas" agree with it, presets resolve
-    to per-backend tuning tables."""
+    """ops-layer routing over a stacked pool: impl="ref" is the oracle on
+    the layer it names, impl="paged_decode_attention"/"pallas" agree with
+    it, presets resolve to per-backend tuning tables."""
     q, kp, vp, bt, cl = _paged_case(11, 2, 8, 2, 32, 8, 4, [32, 11],
                                     jnp.float32)
-    want = ops.paged_attention(q, kp, vp, bt, cl, impl="ref")
+    ks, vs = _stacked(kp, 1), _stacked(vp, 1)
+    want = ops.paged_attention(q, ks, vs, bt, cl, 1, impl="ref")
     np.testing.assert_allclose(
         np.asarray(ref.ref_paged_attention(q, kp, vp, bt, cl)),
         np.asarray(want), rtol=0, atol=0)
     for impl in ("paged_decode_attention", "pallas"):
-        got = ops.paged_attention(q, kp, vp, bt, cl, impl=impl, preset="cpu")
+        got = ops.paged_attention(q, ks, vs, bt, cl, 1, impl=impl,
+                                  preset="cpu")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
     assert ops.kernel_tuning("v5e").pages_per_block > \
